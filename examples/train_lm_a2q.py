@@ -17,10 +17,10 @@ an int8 wire with error feedback by giving the Runtime a GradCompressConfig
 and carrying the residual pair in the train state::
 
     from repro.dist.collectives import GradCompressConfig, resolve_grad_compress
-    from repro.dist.sharding import ShardingRules, param_specs
+    from repro.dist.sharding import ShardingRules, make_mesh, param_specs
     from repro.train.state import init_grad_err
 
-    mesh  = jax.make_mesh((8,), ("data",))
+    mesh  = make_mesh((8,), ("data",))
     rules = ShardingRules.default(mesh, arch)
     gc    = GradCompressConfig(bits=8, scale_axis="column")   # A2Q+-style scales
     rt    = Runtime(mesh=mesh, rules=rules, grad_compress=gc)

@@ -1,8 +1,1 @@
-"""A2Q reproduction package root.
-
-Importing ``repro`` applies the jax compatibility shims in
-:mod:`repro._compat` (notably ``jax.shard_map`` on older jax releases) so
-every entrypoint — tests, launchers, subprocess bodies — sees one API surface.
-"""
-
-from repro import _compat  # noqa: F401
+"""A2Q reproduction package root."""

@@ -109,40 +109,12 @@ def resolve_grad_compress(cfg: Optional[GradCompressConfig], mesh) -> Optional[G
 
 
 def _static_axis_size(axis) -> Optional[int]:
-    """Resolve a mesh axis size at trace time, or ``None`` if unbound.
-
-    ``jax.lax.psum(1, axis)`` alone is unreliable: depending on the jax
-    version it may come back traced inside ``shard_map``, so a guard keyed on
-    ``isinstance(..., int)`` silently never fires.  Prefer the axis
-    environment, which is static whenever the axis is bound.
-    """
-    axes = (axis,) if isinstance(axis, (str, int)) else tuple(axis)
-    size = 1
-    for a in axes:
-        n: Optional[int] = None
-        axis_size = getattr(jax.lax, "axis_size", None)
-        if axis_size is not None:
-            try:
-                n = int(axis_size(a))
-            except Exception:
-                n = None
-        if n is None:
-            try:
-                from jax._src.core import get_axis_env
-
-                n = int(get_axis_env().axis_size(a))
-            except Exception:
-                n = None
-        if n is None:
-            try:
-                m = jax.lax.psum(1, a)
-                n = m if isinstance(m, int) else None
-            except Exception:
-                n = None
-        if n is None:
-            return None
-        size *= n
-    return size
+    """Size of a mesh axis (or tuple of axes) at trace time, or ``None``
+    when the axis is not bound (outside ``shard_map``)."""
+    try:
+        return int(jax.lax.axis_size(axis))
+    except NameError:
+        return None
 
 
 def quantize_shared_scale(y: jnp.ndarray, axis, bits: int, scale_axis: str = "tensor"):

@@ -21,7 +21,8 @@ back to replication.
 
 ``param_specs`` / ``cache_specs`` walk boxed-param / decode-cache pytrees and
 return ``PartitionSpec`` trees; ``constrain`` is the mesh-optional
-``with_sharding_constraint`` used inside the model forward pass.
+``with_sharding_constraint`` used inside the model forward pass, and
+``make_mesh`` builds the meshes it accepts.
 """
 
 from __future__ import annotations
@@ -31,7 +32,7 @@ import math
 from typing import Optional, Sequence
 
 import jax
-from jax.sharding import NamedSharding
+from jax.sharding import AxisType, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.nn.module import Boxed
@@ -42,6 +43,7 @@ __all__ = [
     "param_specs",
     "cache_specs",
     "constrain",
+    "make_mesh",
 ]
 
 
@@ -265,3 +267,13 @@ def constrain(x, mesh, spec: P):
     if mesh is None:
         return x
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str], devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``.  Its default, ``Explicit``,
+    refuses the ``with_sharding_constraint`` calls of ``constrain``: the
+    model lets GSPMD propagate shardings between those constraints."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes),
+        devices=devices,
+    )

@@ -11,8 +11,11 @@ into VMEM — the ``(B, MB * bs, ...)`` contiguous view is never materialized
 (the jnp twin ``ref.ref_paged_attention`` materializes it; `ops.py` picks).
 
 Softmax is the same fp32 online (running max / sum / accumulator) scheme as
-``flash_attention.py``; GQA is handled by gridding over KV heads with the
-``G = H // KV`` query group as the row dim of each score panel.  Key validity
+``flash_attention.py``.  Each grid step ``(b, j)`` loads one pool block
+with all of its KV heads (the ``(KV, Dh)`` trailing dims stay whole, as the
+TPU block-tiling rule requires) and loops over the heads in the kernel; GQA
+puts the ``G = H // KV`` query group in the row dim of each head's score
+panel.  Key validity
 comes from the per-row length: position ``j * bs + o`` participates iff it is
 ``< length`` — dead rows (length 0) produce a zero output via the flush-time
 denominator guard, never a NaN.
@@ -66,8 +69,9 @@ def _unpack_nibbles_f32(u: jnp.ndarray) -> jnp.ndarray:
     """Packed uint8 ``(bs, D // 2)`` -> fp32 codes ``(bs, D)`` (element 2i in
     the low nibble, 2i+1 in the high; ``(x ^ 8) - 8`` sign extension) —
     in-register twin of the layer-side ``_unpack_nibbles``."""
-    lo = (u & 0xF).astype(jnp.int32)
-    hi = (u >> 4).astype(jnp.int32)
+    x = u.astype(jnp.int32)  # Mosaic has no 8-bit shifts; widen first
+    lo = x & 0xF
+    hi = (x >> 4) & 0xF
     se = lambda x: (x ^ 8) - 8
     codes = jnp.stack([se(lo), se(hi)], axis=-1)
     return codes.reshape(u.shape[0], u.shape[1] * 2).astype(jnp.float32)
@@ -76,40 +80,29 @@ def _unpack_nibbles_f32(u: jnp.ndarray) -> jnp.ndarray:
 def paged_attention_kernel(
     bt_ref,  # (B, MB) scalar-prefetch block table
     len_ref,  # (B,)   scalar-prefetch per-row lengths
-    q_ref,  # (1, 1, G, Dh)
-    k_ref,  # (1, bs, 1, Dh) — the pool block bt[b, j]; int8 when quantized
-    v_ref,  # (1, bs, 1, Dh)
+    q_ref,  # (1, KV, G, Dh)
+    k_ref,  # (1, bs, KV, Dh) — every KV head of pool block bt[b, j]; int8 when quantized
+    v_ref,  # (1, bs, KV, Dh)
     *rest,  # quantized: (ks_ref, vs_ref, o_ref, scratch...) else (o_ref, ...)
     scale: float,
     block_size: int,
     mb_steps: int,
+    kv_heads: int,
     quantized: bool,
     packed: bool = False,
     window: Optional[int] = None,
 ):
     if quantized:
-        ks_ref, vs_ref = rest[0], rest[1]  # (1, bs, 1) fp32 per-slot scales
-    o_ref, m_ref, l_ref, acc_ref = rest[-4:]
+        ks_ref, vs_ref = rest[0], rest[1]  # (1, bs, KV) fp32 per-slot scales
+    o_ref, m_ref, l_ref, acc_ref = rest[-4:]  # scratch (KV, G, 1) x2, (KV, G, Dh)
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0, 0].astype(jnp.float32) * scale  # (G, Dh)
-    if packed:
-        k = _unpack_nibbles_f32(k_ref[0, :, 0])  # (bs, Dh) from (bs, Dh // 2)
-    else:
-        k = k_ref[0, :, 0].astype(jnp.float32)  # (bs, Dh)
-    if quantized:
-        # in-register dequant: the fp32 K block exists only in VMEM
-        k = k * ks_ref[0, :, 0][:, None]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (G, bs)
 
     length = len_ref[b]
     kpos = j * block_size + jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1)
@@ -119,32 +112,44 @@ def paged_attention_kernel(
         # window admits keys in (length - 1 - window, length - 1], i.e.
         # kpos >= length - window
         valid &= kpos >= length - window
-    s = jnp.where(valid, s, _NEG_INF)  # (G, bs) via broadcast
 
-    m_prev = m_ref[...]  # (G, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    p = jnp.where(valid, p, 0.0)
-    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-    m_ref[...] = m_new
-    if packed:
-        v = _unpack_nibbles_f32(v_ref[0, :, 0])
-    else:
-        v = v_ref[0, :, 0].astype(jnp.float32)
-    if quantized:
-        v = v * vs_ref[0, :, 0][:, None]
-    pv = jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    acc_ref[...] = alpha * acc_ref[...] + pv
+    def load(ref, h):
+        x = ref[0, :, h, :]  # (bs, Dh), or (bs, Dh // 2) packed
+        return _unpack_nibbles_f32(x) if packed else x.astype(jnp.float32)
+
+    # the block holds every KV head (the pool's trailing (KV, Dh) dims are
+    # whole, which is what the TPU tiling rule asks of a block); each head's
+    # G-query panel runs its own online-softmax update
+    for h in range(kv_heads):
+        q = q_ref[0, h].astype(jnp.float32) * scale  # (G, Dh)
+        k = load(k_ref, h)
+        if quantized:
+            # in-register dequant: the fp32 K block exists only in VMEM
+            k = k * ks_ref[0, :, h][:, None]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )  # (G, bs)
+        s = jnp.where(valid, s, _NEG_INF)
+
+        m_prev = m_ref[h]  # (G, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[h] = m_new
+        v = load(v_ref, h)
+        if quantized:
+            v = v * vs_ref[0, :, h][:, None]
+        pv = jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        acc_ref[h] = alpha * acc_ref[h] + pv
 
     @pl.when(j == mb_steps - 1)
     def _flush():
         l = l_ref[...]
         norm = jnp.where(l > 0.0, 1.0 / jnp.maximum(l, 1e-30), 0.0)
-        o_ref[0, 0] = (acc_ref[...] * norm).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] * norm).astype(o_ref.dtype)
 
 
 def paged_attention_pallas(
@@ -181,32 +186,29 @@ def paged_attention_pallas(
 
     kernel = functools.partial(
         paged_attention_kernel, scale=scale, block_size=bs, mb_steps=MB,
-        quantized=quantized, packed=packed, window=window,
+        kv_heads=KV, quantized=quantized, packed=packed, window=window,
     )
     pool_spec = pl.BlockSpec(
-        (1, bs, 1, Dhp), lambda b, h, j, bt_ref, len_ref: (bt_ref[b, j], 0, h, 0)
+        (1, bs, KV, Dhp), lambda b, j, bt_ref, len_ref: (bt_ref[b, j], 0, 0, 0)
     )
-    in_specs = [
-        pl.BlockSpec((1, 1, G, Dh), lambda b, h, j, bt_ref, len_ref: (b, h, 0, 0)),
-        pool_spec,
-        pool_spec,
-    ]
+    row_spec = pl.BlockSpec((1, KV, G, Dh), lambda b, j, bt_ref, len_ref: (b, 0, 0, 0))
+    in_specs = [row_spec, pool_spec, pool_spec]
     operands = [q, kp, vp]
     if quantized:
         scale_spec = pl.BlockSpec(
-            (1, bs, 1), lambda b, h, j, bt_ref, len_ref: (bt_ref[b, j], 0, h)
+            (1, bs, KV), lambda b, j, bt_ref, len_ref: (bt_ref[b, j], 0, 0)
         )
         in_specs += [scale_spec, scale_spec]
         operands += [kps.astype(jnp.float32), vps.astype(jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, KV, MB),
+        grid=(B, MB),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, Dh), lambda b, h, j, bt_ref, len_ref: (b, h, 0, 0)),
+        out_specs=row_spec,
         scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, Dh), jnp.float32),
+            pltpu.VMEM((KV, G, 1), jnp.float32),
+            pltpu.VMEM((KV, G, 1), jnp.float32),
+            pltpu.VMEM((KV, G, Dh), jnp.float32),
         ],
     )
     return pl.pallas_call(
@@ -239,7 +241,7 @@ def paged_mla_attention_kernel(
 ):
     idx = 0
     if quantized:
-        ckvs_ref, kpes_ref = rest[idx], rest[idx + 1]  # (1, bs) fp32 per-token scales
+        ckvs_ref, kpes_ref = rest[idx], rest[idx + 1]  # (1, bs, 1) fp32 per-token scales
         idx += 2
     if act_bits is not None:
         aq_ref = rest[idx]  # (1, 1) fp32 activation-quantizer scale
@@ -262,8 +264,8 @@ def paged_mla_attention_kernel(
         ckv = ckv_ref[0].astype(jnp.float32)
         kpe = kpe_ref[0].astype(jnp.float32)
     if quantized:
-        ckv = ckv * ckvs_ref[0][:, None]
-        kpe = kpe * kpes_ref[0][:, None]
+        ckv = ckv * ckvs_ref[0]
+        kpe = kpe * kpes_ref[0]
     if act_bits is not None:
         # The absorb path runs the latent through the up-projection's A2Q
         # activation quantizer; replay the fake-quant on the dequantized
@@ -351,11 +353,14 @@ def paged_mla_attention_pallas(
     ]
     operands = [q_lat, q_pe, ckvp, kpep]
     if quantized:
+        # a trailing unit axis makes the block's last two dims (bs, 1):
+        # bs a multiple of 8 and 1 the whole axis, as TPU tiling requires
         scale_spec = pl.BlockSpec(
-            (1, bs), lambda b, j, bt_ref, len_ref: (bt_ref[b, j], 0)
+            (1, bs, 1), lambda b, j, bt_ref, len_ref: (bt_ref[b, j], 0, 0)
         )
         in_specs += [scale_spec, scale_spec]
-        operands += [ckvs.astype(jnp.float32), kpes.astype(jnp.float32)]
+        operands += [ckvs.astype(jnp.float32)[..., None],
+                     kpes.astype(jnp.float32)[..., None]]
     if act_bits is not None:
         in_specs.append(pl.BlockSpec((1, 1), lambda b, j, bt_ref, len_ref: (0, 0)))
         operands.append(jnp.asarray(aq_scale, jnp.float32).reshape(1, 1))

@@ -51,6 +51,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import SHAPES, applicable_shapes, get_arch, input_specs
 from repro.dist.collectives import GradCompressConfig, resolve_grad_compress
 from repro.dist.sharding import ShardingRules, cache_specs, param_specs
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.models.lm import Runtime, init_cache, init_lm
 from repro.models.steps import build_prefill_step, build_serve_step, build_train_step
@@ -427,6 +428,7 @@ def main():
     ap.add_argument("--out", default="experiments/dryrun")
     ap.add_argument("--no-costing", action="store_true", help="compile-only (skip roofline variants)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     from repro.configs import ARCH_NAMES
 

@@ -4,7 +4,7 @@ real launcher) ever calls it."""
 
 from __future__ import annotations
 
-import jax
+from repro.dist.sharding import make_mesh
 
 __all__ = ["make_production_mesh"]
 
@@ -17,4 +17,4 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)

@@ -55,12 +55,21 @@ import jax
 import numpy as np
 
 from repro.configs import get_arch, reduced
+from repro.launch.cache import enable_compile_cache
 from repro.models.lm import Runtime, init_lm
 from repro.nn.module import unbox
 from repro.obs import Obs
 from repro.obs.headroom import engine_headroom
-from repro.serve.engine import PagedServeEngine, ServeEngine, deploy_params, parity_up_to_ties
+from repro.serve.engine import (
+    PagedServeEngine, ServeEngine, init_deployed_lm, parity_up_to_ties,
+)
 from repro.serve.sampling import SampleConfig
+
+
+# greedy-margin tie tolerance of the int8-KV parity bound (serve/README.md
+# "parity bound"): a token may differ from the float reference only where
+# the reference's top-2 logit margin is at most this
+PARITY_EPS = 0.05
 
 
 def _spec_report(engine) -> dict:
@@ -96,7 +105,9 @@ def _report(tag: str, engine) -> dict:
     return tp
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
+    """The launcher's flags, validated, with the implied ones set
+    (``--int-chain`` => ``--int-forward`` => ``--deploy-int8``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -155,7 +166,7 @@ def main(argv=None):
                     help="run paged AND contiguous engines; fail on any token mismatch")
     ap.add_argument("--parity-eps", type=float, default=None,
                     help="greedy-margin tie tolerance for --parity-check with --kv-int8 "
-                         "(default 0.05; lossless configs always compare exactly)")
+                         f"(default {PARITY_EPS}; lossless configs always compare exactly)")
     ap.add_argument("--trace", default=None, metavar="PATH",
                     help="record request-span traces and write Chrome trace-event "
                          "JSON here (load in Perfetto / chrome://tracing)")
@@ -192,29 +203,35 @@ def main(argv=None):
         ap.error("--spec-draft only affects speculative decoding; add --spec-k")
     if args.spec_k > 0 and args.sample != "greedy":
         ap.error("--spec-k is lossless for greedy decoding only")
-
-    arch = get_arch(args.arch)
-    if args.reduced:
-        arch = reduced(arch)
-    key = jax.random.PRNGKey(args.seed)
-    params = unbox(init_lm(key, arch))
     if args.int_chain:
         args.int_forward = True  # chaining is a mode of the integer fast path
     if args.int_forward:
         args.deploy_int8 = True  # the W8A8 path consumes the deployed artifact
-    if args.deploy_int8:
-        params = deploy_params(params, arch.quant)
-        print("serving deployed int8 weights (A2Q-guaranteed accumulator safety)")
-    if args.int_chain:
-        print("int-chain: activation quantization folded into the W8A8 kernel "
-              "(int8 codes chained between deployed layers)")
-    elif args.int_forward:
-        print("int-forward: deployed linears run the fused W8A8 integer kernel")
+    return args
 
-    rng = np.random.default_rng(args.seed)
-    # common material is *prepended* to the per-request prompt_len tail:
-    # a pinned preamble first (prefilled once, never evicted), then an
-    # optional shared prefix (cached from the first request that donates it)
+
+def load_arch(args):
+    arch = get_arch(args.arch)
+    return reduced(arch) if args.reduced else arch
+
+
+def load_params(arch, args) -> dict:
+    """Random weights from ``--seed``; with ``--deploy-int8`` the deployed
+    int8 artifact, built a layer at a time (``init_deployed_lm``) so that a
+    published-width model never holds its float weights whole."""
+    key = jax.random.PRNGKey(args.seed)
+    if args.deploy_int8:
+        return init_deployed_lm(key, arch)
+    return unbox(init_lm(key, arch))
+
+
+def make_prompts(arch, args, seed=None):
+    """``(preamble, prompts)``: ``--requests`` prompts of ``--prompt-len``
+    random tokens from ``seed`` (default ``--seed``).  Common material is
+    *prepended* to each prompt: a pinned preamble first (prefilled once,
+    never evicted), then an optional shared prefix (cached from the first
+    request that donates it)."""
+    rng = np.random.default_rng(args.seed if seed is None else seed)
     preamble = (rng.integers(0, arch.vocab, (args.pin_prompt,)).astype(np.int32)
                 if args.pin_prompt > 0 else None)
     common = (rng.integers(0, arch.vocab, (args.shared_prefix,)).astype(np.int32)
@@ -223,6 +240,71 @@ def main(argv=None):
     prompts = [np.concatenate(head + [rng.integers(0, arch.vocab, (args.prompt_len,)).astype(np.int32)])
                if head else rng.integers(0, arch.vocab, (args.prompt_len,)).astype(np.int32)
                for _ in range(args.requests)]
+    return preamble, prompts
+
+
+def paged_engine(arch, params, args, *, sample=None, decode_kernel=None, obs=None,
+                 preamble=None):
+    """The paged (or speculative) engine the flags describe; ``sample`` and
+    ``decode_kernel`` override the flags' values when given."""
+    if sample is None:
+        sample = SampleConfig(method=args.sample, temperature=args.temperature,
+                              top_k=args.top_k)
+    if decode_kernel is None:
+        decode_kernel = args.decode_kernel
+    kw = dict(
+        batch=args.batch, max_seq=args.max_seq,
+        block_size=args.block_size, prefill_chunk=args.prefill_chunk,
+        num_blocks=args.num_blocks, sample=sample, seed=args.seed,
+        kv_quant=args.kv_int8, kv_bits=args.kv_bits,
+        prefix_share=args.prefix_share,
+        eos_id=args.eos_id, decode_steps=args.decode_steps, obs=obs,
+        rt=Runtime(decode_kernel=decode_kernel, int_forward=args.int_forward,
+                   int_chain=args.int_chain),
+    )
+    if args.spec_k > 0:
+        from repro.serve.spec import ModelDrafter, SpecServeEngine
+
+        drafter = None
+        if args.spec_draft != "self-int8":
+            darch = get_arch(args.spec_draft)
+            if args.reduced:
+                darch = reduced(darch)
+            if darch.vocab != arch.vocab:
+                raise SystemExit(
+                    f"draft config {args.spec_draft} vocab {darch.vocab} != "
+                    f"target vocab {arch.vocab}"
+                )
+            dparams = unbox(init_lm(jax.random.PRNGKey(args.seed + 1), darch))
+            drafter = ModelDrafter(
+                darch, dparams, slots=args.batch, max_seq=args.max_seq,
+                spec_k=args.spec_k, block_size=args.block_size,
+                prefill_chunk=args.prefill_chunk,
+            )
+        e = SpecServeEngine(arch, params, spec_k=args.spec_k, drafter=drafter, **kw)
+    else:
+        e = PagedServeEngine(arch, params, **kw)
+    if preamble is not None:
+        pinned = e.pin_prompt(preamble)
+        print(f"pinned system preamble: {pinned} of {len(preamble)} tokens "
+              f"({pinned // e.cache.block_size} blocks, never evicted)")
+    return e
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    enable_compile_cache()
+    arch = load_arch(args)
+    params = load_params(arch, args)
+    if args.deploy_int8:
+        print("serving deployed int8 weights (A2Q-guaranteed accumulator safety)")
+    if args.int_chain:
+        print("int-chain: activation quantization folded into the W8A8 kernel "
+              "(int8 codes chained between deployed layers)")
+    elif args.int_forward:
+        print("int-forward: deployed linears run the fused W8A8 integer kernel")
+
+    preamble, prompts = make_prompts(arch, args)
     sample = SampleConfig(method=args.sample, temperature=args.temperature, top_k=args.top_k)
     decode_kernel = args.decode_kernel
     if args.parity_check and (args.sample != "greedy" or decode_kernel):
@@ -241,45 +323,6 @@ def main(argv=None):
         print(f"eos-auto: eos_id={args.eos_id} (request 0's token at step {len(ptoks) // 2})")
 
     obs = Obs(trace=bool(args.trace))
-
-    def paged_engine():
-        kw = dict(
-            batch=args.batch, max_seq=args.max_seq,
-            block_size=args.block_size, prefill_chunk=args.prefill_chunk,
-            num_blocks=args.num_blocks, sample=sample, seed=args.seed,
-            kv_quant=args.kv_int8, kv_bits=args.kv_bits,
-            prefix_share=args.prefix_share,
-            eos_id=args.eos_id, decode_steps=args.decode_steps, obs=obs,
-            rt=Runtime(decode_kernel=decode_kernel, int_forward=args.int_forward,
-                       int_chain=args.int_chain),
-        )
-        if args.spec_k > 0:
-            from repro.serve.spec import ModelDrafter, SpecServeEngine
-
-            drafter = None
-            if args.spec_draft != "self-int8":
-                darch = get_arch(args.spec_draft)
-                if args.reduced:
-                    darch = reduced(darch)
-                if darch.vocab != arch.vocab:
-                    raise SystemExit(
-                        f"draft config {args.spec_draft} vocab {darch.vocab} != "
-                        f"target vocab {arch.vocab}"
-                    )
-                dparams = unbox(init_lm(jax.random.PRNGKey(args.seed + 1), darch))
-                drafter = ModelDrafter(
-                    darch, dparams, slots=args.batch, max_seq=args.max_seq,
-                    spec_k=args.spec_k, block_size=args.block_size,
-                    prefill_chunk=args.prefill_chunk,
-                )
-            e = SpecServeEngine(arch, params, spec_k=args.spec_k, drafter=drafter, **kw)
-        else:
-            e = PagedServeEngine(arch, params, **kw)
-        if preamble is not None:
-            pinned = e.pin_prompt(preamble)
-            print(f"pinned system preamble: {pinned} of {len(preamble)} tokens "
-                  f"({pinned // e.cache.block_size} blocks, never evicted)")
-        return e
 
     report: dict = {
         "arch": args.arch, "paged": bool(args.paged or args.parity_check),
@@ -307,7 +350,8 @@ def main(argv=None):
         else:
             outs_c = contig.generate(prompts, max_new=args.max_new)
             reqs_c = contig.last_requests
-        pagede = paged_engine()
+        pagede = paged_engine(arch, params, args, sample=sample,
+                              decode_kernel=decode_kernel, obs=obs, preamble=preamble)
         outs_p = pagede.generate(prompts, max_new=args.max_new)
         report["contiguous"] = _report("contiguous", contig)
         report["paged_engine"] = _report("paged", pagede)
@@ -324,7 +368,7 @@ def main(argv=None):
         if args.kv_int8:
             # int8 KV is lossy: token parity holds up to quantization ties
             # (see serve.engine.parity_up_to_ties and serve/README.md "parity bound")
-            eps = 0.05 if args.parity_eps is None else args.parity_eps
+            eps = PARITY_EPS if args.parity_eps is None else args.parity_eps
             ok, ties, detail = parity_up_to_ties(reqs_c, outs_p, eps)
             report["parity_eps"] = eps
             report["parity_sub_margin_ties"] = ties
@@ -340,7 +384,8 @@ def main(argv=None):
         outs = outs_p
         engine = pagede
     elif args.paged:
-        engine = paged_engine()
+        engine = paged_engine(arch, params, args, sample=sample,
+                              decode_kernel=decode_kernel, obs=obs, preamble=preamble)
         outs = engine.generate(prompts, max_new=args.max_new)
         report["paged_engine"] = _report("paged", engine)
         cache = engine.cache

@@ -141,11 +141,21 @@ def main(argv=None):
     if not 0.0 <= args.fault_rate < 1.0:
         ap.error("--fault-rate must be in [0, 1)")
 
+    import jax
+
     from repro.configs import get_arch, reduced
+    from repro.launch.cache import enable_compile_cache
     from repro.serve.cluster import (
         InProcessReplica, ReplicaConfig, Router, SubprocessReplica,
         make_cluster_configs, parse_disagg,
     )
+
+    if args.transport == "subproc" and jax.default_backend() == "tpu":
+        # libtpu admits one process per chip host: this process now holds
+        # the chips, so spawned replica processes could never open them
+        ap.error("--transport subproc cannot run on a TPU host (one process "
+                 "per chip); use --transport inproc")
+    enable_compile_cache()
 
     arch = get_arch(args.arch)
     if args.reduced:
@@ -173,7 +183,6 @@ def main(argv=None):
     if args.transport == "inproc":
         # share one host params copy across replicas (and the parity engine)
         from repro.serve.cluster.replica import build_engine  # noqa: F401
-        import jax
         from repro.models.lm import init_lm
         from repro.nn.module import unbox
 

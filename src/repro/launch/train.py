@@ -16,28 +16,95 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
-from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.configs import get_arch, reduced
 from repro.data.synthetic import TokenStream
 from repro.dist.collectives import GradCompressConfig, resolve_grad_compress
-from repro.dist.sharding import ShardingRules
-from repro.launch.mesh import make_production_mesh
+from repro.dist.sharding import ShardingRules, make_mesh, param_specs
+from repro.launch.cache import enable_compile_cache
 from repro.models.lm import Runtime, init_lm
 from repro.models.steps import build_train_step
 from repro.nn.module import unbox
 from repro.optim.optimizers import adamw, adafactor, sgdm
 from repro.optim.schedules import cosine_with_warmup
+from repro.train.checkpoint import install_signal_handler
 from repro.train.elastic import StragglerWatchdog, plan_mesh
-from repro.train.state import init_grad_err
+from repro.train.state import init_grad_err, make_state_specs, specs_to_shardings
 from repro.train.trainer import Trainer
 
 _OPTS = {"adamw": adamw, "adafactor": adafactor, "sgdm": sgdm}
+
+
+def train(
+    arch,
+    *,
+    steps: int,
+    batch: int,
+    seq: int,
+    lr: float = 1e-3,
+    optimizer: str = "adamw",
+    devices=None,
+    use_mesh: bool = True,
+    grad_compress: Optional[GradCompressConfig] = None,
+    ckpt_dir: Optional[str] = None,
+    ckpt_every: int = 50,
+    seed: int = 0,
+):
+    """Train ``arch`` for ``steps`` steps on ``devices`` (default: all).
+
+    More than one device (and ``use_mesh``) builds the planned data x model
+    mesh over them and places the train state by ``make_state_specs``;
+    otherwise the state lives on the first device.  Returns the
+    ``TrainLoopResult`` (one history record per step)."""
+    devices = list(devices) if devices is not None else jax.devices()
+    mesh = None
+    rules = None
+    if use_mesh and len(devices) > 1:
+        plan = plan_mesh(len(devices), model_divisors=[s.attn.heads for s in arch.stacks if s.attn])
+        mesh = make_mesh(plan["shape"], plan["axes"], devices=devices)
+        rules = ShardingRules.default(mesh, arch)
+        print(f"mesh: {dict(zip(plan['axes'], plan['shape']))}")
+    ep_axis = "model" if (mesh is not None and any(s.moe for s in arch.stacks)) else None
+    rt = Runtime(mesh=mesh, ep_axis=ep_axis, rules=rules, grad_compress=grad_compress)
+
+    boxed = init_lm(jax.random.PRNGKey(seed), arch)
+    params = unbox(boxed)
+    opt = _OPTS[optimizer]()
+    state = {"params": params, "opt_state": opt.init(params), "step": jnp.zeros((), jnp.int32)}
+    gc = resolve_grad_compress(grad_compress, mesh)
+    if grad_compress is not None and gc is None:
+        print("grad-compress requested but no multi-device data axis: running uncompressed")
+    if gc is not None:
+        pspecs = param_specs(boxed, mesh, rules)
+        state["grad_err"] = init_grad_err(params, mesh.shape[gc.axis], pspecs=pspecs, axis=gc.axis)
+        print(f"grad-compress: int{gc.bits} wire over '{gc.axis}' ({gc.scale_axis} scale)")
+
+    sched = cosine_with_warmup(lr, warmup=max(steps // 20, 1), total=steps)
+    step_fn = build_train_step(arch, opt, rt, lr_schedule=sched)
+
+    stream = TokenStream(vocab=arch.vocab, seq_len=seq, global_batch=batch, seed=seed)
+    trainer = Trainer(
+        step_fn,
+        stream.batch,
+        ckpt_dir=ckpt_dir,
+        ckpt_every=ckpt_every,
+        log_every=1,
+        watchdog=StragglerWatchdog(),
+    )
+    # older checkpoints have no grad_err leaves; residuals restart from zeros
+    state, start = trainer.maybe_restore(state, allow_missing=gc is not None)
+    if start:
+        print(f"resumed from step {start}")
+    if mesh is not None:
+        specs = make_state_specs(boxed, opt, mesh, rules, grad_compress=gc)
+        state = jax.device_put(state, specs_to_shardings(specs, mesh))
+    if ckpt_dir:
+        install_signal_handler(trainer.emergency_save)
+    return trainer.run(state, steps, start_step=start)
 
 
 def main(argv=None):
@@ -69,59 +136,20 @@ def main(argv=None):
     arch = get_arch(args.arch)
     if args.reduced:
         arch = reduced(arch)
-
-    mesh = None
-    rules = None
-    if args.mesh == "auto" and jax.device_count() > 1:
-        plan = plan_mesh(jax.device_count(), model_divisors=[s.attn.heads for s in arch.stacks if s.attn])
-        mesh = jax.make_mesh(plan["shape"], plan["axes"])
-        rules = ShardingRules.default(mesh, arch)
-        print(f"mesh: {dict(zip(plan['axes'], plan['shape']))}")
-    ep_axis = "model" if (mesh is not None and any(s.moe for s in arch.stacks)) else None
+    enable_compile_cache()
     grad_compress = None
     if args.grad_compress_bits:
         grad_compress = GradCompressConfig(
             bits=args.grad_compress_bits, scale_axis=args.grad_compress_scale
         )
-    rt = Runtime(mesh=mesh, ep_axis=ep_axis, rules=rules, grad_compress=grad_compress)
-
-    key = jax.random.PRNGKey(args.seed)
-    boxed = init_lm(key, arch)
-    params = unbox(boxed)
-    optimizer = _OPTS[args.optimizer]()
-    state = {"params": params, "opt_state": optimizer.init(params), "step": jnp.zeros((), jnp.int32)}
-    gc = resolve_grad_compress(grad_compress, mesh)
-    if grad_compress is not None and gc is None:
-        print("grad-compress requested but no multi-device data axis: running uncompressed")
-    if gc is not None:
-        from repro.dist.sharding import param_specs
-
-        pspecs = param_specs(boxed, mesh, rules) if rules is not None else None
-        state["grad_err"] = init_grad_err(params, mesh.shape[gc.axis], pspecs=pspecs, axis=gc.axis)
-        print(f"grad-compress: int{gc.bits} wire over '{gc.axis}' ({gc.scale_axis} scale)")
-
-    sched = cosine_with_warmup(args.lr, warmup=max(args.steps // 20, 1), total=args.steps)
-    step_fn = build_train_step(arch, optimizer, rt, lr_schedule=sched)
-
-    stream = TokenStream(vocab=arch.vocab, seq_len=args.seq, global_batch=args.batch, seed=args.seed)
-    trainer = Trainer(
-        step_fn,
-        stream.batch,
-        ckpt_dir=args.ckpt_dir,
-        ckpt_every=args.ckpt_every,
-        watchdog=StragglerWatchdog(),
+    result = train(
+        arch, steps=args.steps, batch=args.batch, seq=args.seq, lr=args.lr,
+        optimizer=args.optimizer, use_mesh=args.mesh == "auto",
+        grad_compress=grad_compress, ckpt_dir=args.ckpt_dir,
+        ckpt_every=args.ckpt_every, seed=args.seed,
     )
-    # older checkpoints have no grad_err leaves; residuals restart from zeros
-    state, start = trainer.maybe_restore(state, allow_missing=gc is not None)
-    if start:
-        print(f"resumed from step {start}")
-    from repro.train.checkpoint import install_signal_handler
-
-    if args.ckpt_dir:
-        install_signal_handler(trainer.emergency_save)
-
-    result = trainer.run(state, args.steps, start_step=start)
-    for rec in result.history[:3] + result.history[-3:]:
+    hist = result.history
+    for rec in hist if len(hist) <= 6 else hist[:3] + hist[-3:]:
         print({k: round(v, 4) if isinstance(v, float) else v for k, v in rec.items()})
     if result.straggler_events:
         print(f"straggler events: {len(result.straggler_events)}")

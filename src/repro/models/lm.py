@@ -86,13 +86,17 @@ class Runtime:
         return P(self.rules.rules.get("batch") or None, *([None] * (ndim - 1)))
 
 
-def init_lm(key, arch: ArchConfig):
+def init_lm(key, arch: ArchConfig, *, stack_init=init_stack):
+    """Boxed params.  ``stack_init(key, arch, stack_cfg)`` builds each layer
+    stack (default :func:`nn.transformer.init_stack`); the serve path swaps
+    in one that deploys each layer as it is made
+    (``serve.engine.init_deployed_lm``)."""
     ks = jax.random.split(key, 8)
     params: dict = {}
     if arch.family != "audio":
         params["embed"] = init_embedding(ks[0], arch.vocab, arch.d_model)
     params["stacks"] = {
-        str(i): init_stack(ks[1 + (i % 6)], arch, s) for i, s in enumerate(arch.stacks)
+        str(i): stack_init(ks[1 + (i % 6)], arch, s) for i, s in enumerate(arch.stacks)
     }
     params["final_norm"] = init_norm(arch.d_model, arch.norm)
     if arch.family == "audio":

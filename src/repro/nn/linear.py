@@ -56,6 +56,7 @@ chaining is on — CI-gated).
 from __future__ import annotations
 
 import contextlib
+import functools
 import warnings
 from typing import NamedTuple, Optional, Sequence
 
@@ -180,7 +181,7 @@ def acc_probe_scope(samples: list):
         {"site", "acc_max", "acc_bits", "bound", "spill_int16",
          "in_bits", "in_signed"}
 
-    ``acc_max`` is ``max(|x_codes| @ |q8|)`` over output channels in int64 —
+    ``acc_max`` is ``max(|x_codes| @ |q8|)`` over output channels in int32 —
     an upper bound on the magnitude of *any* partial sum, in any
     accumulation order, for the actual integer operands (the runtime twin of
     the paper's Eq. 11 check, which bounds the same quantity by
@@ -196,20 +197,25 @@ def acc_probe_scope(samples: list):
         _ACTIVE_ACC_PROBE.pop()
 
 
+@functools.partial(jax.jit, static_argnums=2)
+def _acc_max(codes, q8, symmetrized):
+    # on the device, in int32: |codes| <= 255 and each column of |q8| sums
+    # to at most 127 * K, so the product stays below 2**31 for K < 66000
+    xc = codes.astype(jnp.int32)
+    if symmetrized:
+        xc = xc + 128  # stored codes are true - 128 (unsigned-8 ride-along)
+    xc = jnp.abs(xc).reshape(-1, xc.shape[-1])
+    wq = jnp.abs(q8.astype(jnp.int32))
+    return jnp.max(jnp.matmul(xc, wq, preferred_element_type=jnp.int32))
+
+
 def _probe_acc(site, codes, q8, *, in_bits, in_signed, acc_bits, spill_int16,
                symmetrized=False):
     if not _ACTIVE_ACC_PROBE:
         return
     if isinstance(codes, jax.core.Tracer) or isinstance(q8, jax.core.Tracer):
         return  # abstract operands (jit/vmap/scan): nothing to sample
-    import numpy as np
-
-    xc = np.asarray(codes, dtype=np.int64)
-    if symmetrized:
-        xc = xc + 128  # stored codes are true - 128 (unsigned-8 ride-along)
-    xc = np.abs(xc).reshape(-1, xc.shape[-1])
-    wq = np.abs(np.asarray(q8, dtype=np.int64))
-    acc_max = int((xc @ wq).max()) if xc.size and wq.size else 0
+    acc_max = int(_acc_max(codes, q8, symmetrized)) if codes.size and q8.size else 0
     _ACTIVE_ACC_PROBE[-1].append({
         "site": site,
         "acc_max": acc_max,

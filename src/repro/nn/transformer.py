@@ -45,7 +45,7 @@ from repro.nn.ssm import (
     init_rwkv6_timemix,
 )
 
-__all__ = ["init_stack", "apply_stack", "init_stack_cache", "tree_a2q_penalty"]
+__all__ = ["init_block", "init_stack", "apply_stack", "init_stack_cache", "tree_a2q_penalty"]
 
 
 # ---------------------------------------------------------------------------
@@ -94,7 +94,8 @@ def _apply_mlp(p: dict, x, q: QuantConfig, compute_dtype,
 # ---------------------------------------------------------------------------
 
 
-def _init_block(key, arch: ArchConfig, s: StackConfig) -> dict:
+def init_block(key, arch: ArchConfig, s: StackConfig) -> dict:
+    """Boxed params of one block of stack ``s``."""
     d, q = arch.d_model, arch.quant
     ks = jax.random.split(key, 4)
     norm = lambda: init_norm(d, arch.norm)
@@ -248,7 +249,7 @@ def tree_a2q_penalty(p, q: QuantConfig) -> jnp.ndarray:
 def init_stack(key, arch: ArchConfig, s: StackConfig):
     """Stacked (leading ``count`` dim) boxed params for one stack."""
     keys = jax.random.split(key, s.count)
-    stacked = jax.vmap(lambda k: _init_block(k, arch, s))(keys)
+    stacked = jax.vmap(lambda k: init_block(k, arch, s))(keys)
     return with_layers_axis(stacked)
 
 

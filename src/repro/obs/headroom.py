@@ -45,6 +45,14 @@ def _deployed_signed(path: tuple) -> bool:
     return not (len(path) >= 2 and path[-2] == "cm" and path[-1] == "wv")
 
 
+@jax.jit
+def _l1_max(q8):
+    # weights are (..., K, C): channels (accumulators) on the last axis, so
+    # per-channel l1 reduces the K axis.  Reduced on the device in int32
+    # (127 * K is far below 2**31), so no weight copy reaches the host.
+    return jnp.max(jnp.sum(jnp.abs(q8.astype(jnp.int32)), axis=-2))
+
+
 def static_headroom_report(params: dict, quant) -> list:
     """Per-layer worst-case accumulator utilization for a deployed tree.
 
@@ -63,11 +71,7 @@ def static_headroom_report(params: dict, quant) -> list:
             return
         if "q8" in node and "s8" in node:
             signed = _deployed_signed(path)
-            q8 = np.asarray(node["q8"], dtype=np.int64)
-            # weights are (..., K, C): channels (accumulators) on the last
-            # axis, so per-channel l1 reduces the K axis
-            l1 = np.abs(q8).sum(axis=-2)
-            l1_max = float(l1.max()) if l1.size else 0.0
+            l1_max = float(_l1_max(node["q8"])) if node["q8"].size else 0.0
             out.append({
                 "site": ".".join(path),
                 "utilization": float(headroom_utilization(l1_max, N, signed, P)),

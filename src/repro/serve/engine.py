@@ -46,8 +46,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig, QuantConfig
-from repro.models.lm import Runtime, apply_lm, init_cache
+from repro.models.lm import Runtime, apply_lm, init_cache, init_lm
 from repro.nn.linear import deploy_linear
+from repro.nn.module import unbox
+from repro.nn.transformer import init_block
 from repro.obs import Obs
 from repro.serve.paged_cache import PagedKVCache
 from repro.serve.sampling import SampleConfig, sample_tokens
@@ -55,7 +57,7 @@ from repro.serve.scheduler import Scheduler, ServeRequest
 
 __all__ = [
     "ServeEngine", "PagedServeEngine", "Request", "deploy_params", "deploy_boxed",
-    "parity_up_to_ties",
+    "init_deployed_lm", "parity_up_to_ties",
 ]
 
 
@@ -90,6 +92,28 @@ def deploy_params(params: dict, q: QuantConfig) -> dict:
         return node
 
     return walk(params)
+
+
+def init_deployed_lm(key, arch: ArchConfig) -> dict:
+    """``deploy_params(unbox(init_lm(key, arch)), arch.quant)``, built so the
+    float weights of a layer stack never exist whole: each stack is a
+    ``lax.map`` that inits and deploys one layer per iteration (same
+    per-layer keys as ``init_stack``), and the whole build is one jitted
+    program, so the compiler bounds what is live at once — the int8 stacks
+    plus one layer's float weights, or the float embedding and head.  A
+    published-width model whose fp32 weights exceed the device (yi-6b:
+    ~24 GB) deploys this way into its ~7 GB int8 artifact."""
+
+    def stack_init(k, arch, s):
+        def one(layer_key):
+            return deploy_params(unbox(init_block(layer_key, arch, s)), arch.quant)
+
+        return jax.lax.map(one, jax.random.split(k, s.count))
+
+    def build(k):
+        return deploy_params(unbox(init_lm(k, arch, stack_init=stack_init)), arch.quant)
+
+    return jax.jit(build)(key)
 
 
 def deploy_boxed(boxed_tree, q: QuantConfig):

@@ -13,7 +13,7 @@ import pytest
 from repro.configs import get_arch, reduced
 from repro.models.lm import apply_lm, init_lm
 from repro.nn.module import Boxed, unbox
-from repro.serve.engine import deploy_boxed, deploy_params
+from repro.serve.engine import deploy_boxed, deploy_params, init_deployed_lm
 
 KEY = jax.random.PRNGKey(0)
 
@@ -98,6 +98,26 @@ def test_deploy_boxed_mirrors_deploy_params_shapes():
             assert tuple(leaf.value.shape) == tuple(r[k].shape), (path, k)
             assert leaf.value.dtype == r[k].dtype
             assert len(leaf.axes) == r[k].ndim
+
+
+@pytest.mark.parametrize("name", ["yi-6b", "rwkv6-7b", "deepseek-v3-671b"])
+def test_init_deployed_lm_matches_deploy_of_init(name):
+    """The layer-at-a-time deployed init is the deployed full init: same
+    tree, identical int8 codes, float leaves (scales, norms, quantizer
+    state) equal up to jit-vs-eager rounding."""
+    arch = reduced(get_arch(name))
+    want = deploy_params(unbox(init_lm(KEY, arch)), arch.quant)
+    got = init_deployed_lm(KEY, arch)
+    flat_w, tree_w = jax.tree_util.tree_flatten_with_path(want)
+    flat_g, tree_g = jax.tree_util.tree_flatten_with_path(got)
+    assert tree_w == tree_g
+    for (path, w), (_, g) in zip(flat_w, flat_g):
+        assert w.shape == g.shape and w.dtype == g.dtype, path
+        if w.dtype == jnp.int8:
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w), err_msg=str(path))
+        else:
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-6, atol=1e-9,
+                                       err_msg=str(path))
 
 
 @pytest.mark.parametrize("name", ["yi-6b", "deepseek-v3-671b"])

@@ -17,6 +17,17 @@ from repro.serve.scheduler import Scheduler, ServeRequest
 KEY = jax.random.PRNGKey(0)
 
 
+@pytest.fixture(autouse=True)
+def _bound_live_executables_per_test():
+    """This module builds dozens of engines, each with its own jitted
+    steps; kept alive together until the module ends (conftest's purge),
+    their XLA CPU executables made a later compile segfault inside
+    ``test_bursty_skewed_wave_completes_under_block_pressure[4]``.
+    Purging after every test here bounds them to one test's worth."""
+    yield
+    jax.clear_caches()
+
+
 def _params(arch):
     return unbox(init_lm(KEY, arch))
 

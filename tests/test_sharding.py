@@ -72,7 +72,8 @@ def test_batch_axes_multi_pod():
 
 
 def _run_subprocess(body: str, n_dev: int = 8) -> str:
-    code = textwrap.dedent(body)
+    # every script builds its meshes with the Auto-axis helper
+    code = "from repro.dist.sharding import make_mesh\n" + textwrap.dedent(body)
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_dev}"
     env["PYTHONPATH"] = SRC
@@ -114,7 +115,7 @@ def test_sharded_train_step_matches_single_device():
         _, m1 = step1(jax.tree.map(lambda x: x, state), batch)
 
         # (data=2, model=4) mesh
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rules = ShardingRules.default(mesh, arch)
         rt = Runtime(mesh=mesh, rules=rules)
         stepm = jax.jit(build_train_step(arch, opt, rt))
@@ -145,7 +146,7 @@ def test_moe_ep_shard_map_matches_local():
         p = unbox(moe.init_moe(key, 8, cfg, q))
         x = jax.random.normal(key, (4, 8, 8), jnp.float32)
         local = moe.apply_moe(p, x, cfg, q, compute_dtype=jnp.float32)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         with mesh:
             ep = jax.jit(lambda p, x: moe.apply_moe(p, x, cfg, q, ep_axis="model",
                                                     mesh=mesh, compute_dtype=jnp.float32))(p, x)
@@ -173,7 +174,7 @@ def test_moe_ep_over_both_axes_matches_local():
         p = unbox(moe.init_moe(key, 8, cfg, q))
         x = jax.random.normal(key, (4, 8, 8), jnp.float32)
         local = moe.apply_moe(p, x, cfg, q, compute_dtype=jnp.float32)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         with mesh:
             ep = jax.jit(lambda p, x: moe.apply_moe(p, x, cfg, q, ep_axis=("model", "data"),
                                                     mesh=mesh, compute_dtype=jnp.float32))(p, x)
@@ -193,7 +194,7 @@ def test_compressed_psum_error_feedback():
         from jax.sharding import PartitionSpec as P
         from repro.dist.collectives import compressed_psum
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         x = jax.random.normal(jax.random.PRNGKey(0), (8, 64), jnp.float32)
 
         def f(xs, err):
@@ -237,7 +238,7 @@ def test_compressed_grad_training_tracks_uncompressed():
         from repro.train.state import init_grad_err
 
         arch = reduced(get_arch("smollm-135m"))
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         rules = ShardingRules.default(mesh, arch)
         params = unbox(init_lm(jax.random.PRNGKey(0), arch))
         boxed = jax.eval_shape(lambda: init_lm(jax.random.PRNGKey(0), arch))
@@ -319,7 +320,7 @@ def test_compressed_grad_training_on_tp_mesh():
         from repro.train.state import init_grad_err
 
         arch = reduced(get_arch("smollm-135m"))
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rules = ShardingRules.default(mesh, arch)
         params = unbox(init_lm(jax.random.PRNGKey(0), arch))
         boxed = jax.eval_shape(lambda: init_lm(jax.random.PRNGKey(0), arch))
@@ -374,7 +375,7 @@ def test_decode_with_kv_sharded_cache_matches_unsharded():
             stacks=(dataclasses.replace(s0, attn=dataclasses.replace(s0.attn, kv_heads=4)),)
             + arch.stacks[1:],
         )
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rules = ShardingRules.default(mesh, arch)
         params = unbox(init_lm(jax.random.PRNGKey(0), arch))
         cache = init_cache(arch, 8, 32)
@@ -421,7 +422,7 @@ def test_elastic_reshard_restore():
         tree = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
         d = tempfile.mkdtemp()
         ckpt.save(d, tree, 7)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         sh = {"w": NamedSharding(mesh, P("data", "model"))}
         restored, step = ckpt.restore(d, tree, shardings=sh)
         assert step == 7

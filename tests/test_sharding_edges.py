@@ -28,7 +28,7 @@ from repro.dist.collectives import (
     resolve_grad_compress,
     server_shape,
 )
-from repro.dist.sharding import ShardingRules, cache_specs, param_specs, resolve_pspec
+from repro.dist.sharding import ShardingRules, cache_specs, make_mesh, param_specs, resolve_pspec
 from repro.nn.module import box
 
 
@@ -100,7 +100,7 @@ def test_compressed_psum_single_device_contract():
     """On a 1-device mesh the psum is an identity: the 'total' is the
     dequantized payload, the residual is exactly what quantization dropped,
     and total + err reconstructs the payload bit-for-bit."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     x = jax.random.normal(jax.random.PRNGKey(0), (4, 32), jnp.float32)
     err0 = jnp.zeros_like(x)
 
@@ -119,7 +119,7 @@ def test_compressed_psum_single_device_contract():
 
 
 def test_compressed_psum_tree_structure():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     tree = {"a": jnp.ones((2, 4)), "b": {"c": jnp.full((3,), 0.3)}}
     errs = jax.tree.map(jnp.zeros_like, tree)
 
@@ -157,10 +157,10 @@ def test_overflow_guard_raises_at_trace_time():
     """The Eq.-12-style static guard must actually fire: 2**17 shards at
     int16 overflows the int32 accumulator.  AbstractMesh traces the
     shard_map without devices, so the guard is exercised at trace time."""
-    from jax._src.mesh import AbstractMesh
+    from jax.sharding import AbstractMesh
 
     n = 1 << 17
-    am = AbstractMesh((("data", n),))
+    am = AbstractMesh((n,), ("data",))
     x = jax.ShapeDtypeStruct((n, 4), jnp.float32)
 
     def f(xs, es):
@@ -187,7 +187,7 @@ def test_quantize_wire_format(bits, rows, cols):
     """Wire payload contract: int8 for bits<=8 / int16 above, one scale
     scalar for tensor mode, one fp32 scale per output column for column
     mode (rank>=2)."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     y = jax.random.normal(jax.random.PRNGKey(bits), (rows, cols), jnp.float32)
 
     def f(ys):
@@ -215,7 +215,7 @@ def test_per_column_scale_exact_on_column_constant(cols, spread):
     """A payload whose every column is constant is represented exactly by
     per-column scales (each column quantizes to +-qmax), while a shared
     tensor scale loses the small columns — the A2Q+ granularity argument."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     vals = jnp.linspace(1.0, spread, cols)
     x = jnp.tile(vals[None, :], (4, 1)).astype(jnp.float32)
     err0 = jnp.zeros_like(x)
@@ -241,7 +241,7 @@ def test_compressed_psum_column_tree_mixed_ranks():
     """Tree mode with per-column scales: rank>=2 leaves get column scales,
     rank-1 leaves fall back to the tensor scale — both still reconstruct
     payload = total + err on one device."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     tree = {
         "w": jnp.asarray([[0.5, 40.0], [0.5, 40.0]], jnp.float32),
         "b": jnp.asarray([0.1, -0.2, 0.3], jnp.float32),
@@ -263,7 +263,7 @@ def test_compressed_psum_column_tree_mixed_ranks():
 def test_compressed_allreduce_tree_single_device_contract():
     """The global-view (GSPMD) transport on one device: total ~= payload,
     total + local residual reconstructs it, structure preserved."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     tree = {"w": jax.random.normal(jax.random.PRNGKey(0), (4, 6), jnp.float32),
             "s": jnp.float32(0.7)}
     stacked = jax.tree.map(lambda t: t[None], tree)
