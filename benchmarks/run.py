@@ -154,13 +154,9 @@ def main(argv=None):
             results["serve"]["int_chain_requant_dispatches"] == 0,
         "serve_int_chain_decode_not_slower":
             results["serve"]["int_chain_decode_ratio"] >= 0.95,
-        # observability: span tracing live on the megastep hot path costs at
-        # most 5% decode throughput vs the untraced twin (the disabled path
-        # is a null-span identity return; the enabled path is one clock read
-        # + tuple append per span), and the accumulator-headroom telemetry
-        # confirms the deployed integer engine serves strictly inside the
-        # A2Q guarantee: max static L1 utilization < 1.0, zero violations
-        "serve_obs_overhead": results["serve"]["obs_overhead"] <= 1.05,
+        # observability: the accumulator-headroom telemetry confirms the
+        # deployed integer engine serves strictly inside the A2Q guarantee:
+        # max static L1 utilization < 1.0, zero violations
         "serve_acc_headroom_max": results["serve"]["acc_headroom_util_max"] < 1.0,
         "serve_acc_headroom_violations":
             results["serve"]["acc_headroom_violations"] == 0,
@@ -196,11 +192,9 @@ def main(argv=None):
             "serve": results["serve"],
             "cluster": results["cluster"],
             "kernels": results["kernels"]["rows"],
-            # the observability block: tracing overhead on the megastep hot
-            # path and the accumulator-headroom guarantee as measured gauges
+            # the observability block: the accumulator-headroom guarantee
+            # as measured gauges
             "obs": {
-                "overhead": results["serve"]["obs_overhead"],
-                "trace_events": results["serve"]["obs_trace_events"],
                 "acc_headroom_util_max": results["serve"]["acc_headroom_util_max"],
                 "acc_headroom_observed_frac_max":
                     results["serve"]["acc_headroom_observed_frac_max"],

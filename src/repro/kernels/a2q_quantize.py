@@ -116,5 +116,6 @@ def a2q_quantize_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((1, block_c), jnp.float32)],
         interpret=interpret,
+        name="a2q_quantize",
     )(v, t, d)
     return deq, q

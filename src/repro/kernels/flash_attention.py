@@ -148,4 +148,5 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

@@ -305,4 +305,5 @@ def int_matmul_pallas(
         out_shape=jax.ShapeDtypeStruct((M, N), final_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), spill_dtype)],
         interpret=interpret,
+        name="int_matmul",
     )(*operands)

@@ -216,6 +216,7 @@ def paged_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, Dh), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(bt.astype(jnp.int32), lengths.astype(jnp.int32), *operands)
 
 
@@ -380,4 +381,5 @@ def paged_mla_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, R), jnp.float32),
         interpret=interpret,
+        name="paged_mla_attention",
     )(bt.astype(jnp.int32), lengths.astype(jnp.int32), *operands)

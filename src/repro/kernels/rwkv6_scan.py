@@ -118,5 +118,6 @@ def rwkv6_scan_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((Dk, Dv), jnp.float32)],
         interpret=interpret,
+        name="rwkv6_scan",
     )(r, k, v, w, u, initial_state)
     return y, sT

@@ -184,7 +184,8 @@ def apply_lm(
     if frontend_embeds is not None:
         parts.append(frontend_embeds.astype(cd))
     if tokens is not None:
-        parts.append(apply_embedding(params["embed"], tokens, dtype=cd))
+        with jax.named_scope("embed"):
+            parts.append(apply_embedding(params["embed"], tokens, dtype=cd))
     x = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
     B, S, _ = x.shape
     x = constrain(x, rt.mesh, rt.batch_spec(3))
@@ -225,7 +226,8 @@ def apply_lm(
         h = apply_norm(params["final_norm"], x, kind=arch.norm, eps=arch.norm_eps)
         if "head" in params:
             penalty = penalty + linear_penalty(params["head"], arch.quant, True, True)
-        logits = _head_logits(params, arch, h, rt)
+        with jax.named_scope("head"):
+            logits = _head_logits(params, arch, h, rt)
     out_cache = new_cache if cache is not None else None
     if return_hidden:
         return logits, out_cache, penalty, h
@@ -257,7 +259,8 @@ def lm_loss(params, arch: ArchConfig, batch: dict, rt: Optional[Runtime] = None)
         return_hidden=True,
     )
     targets = batch["targets"]
-    loss, ce = _cross_entropy(logits, targets)
+    with jax.named_scope("loss"):
+        loss, ce = _cross_entropy(logits, targets)
 
     metrics = {"ce": ce, "penalty": penalty}
     if arch.mtp_depth > 0 and "mtp" in params:

@@ -62,9 +62,10 @@ def build_train_step(
             return lm_loss(p, arch, batch, rt=rt)
 
         (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
-        grads, gnorm = clip_by_global_norm(grads, grad_clip)
-        lr = lr_schedule(step)
-        new_params, new_opt = optimizer.update(grads, opt_state, params, lr)
+        with jax.named_scope("optimizer"):
+            grads, gnorm = clip_by_global_norm(grads, grad_clip)
+            lr = lr_schedule(step)
+            new_params, new_opt = optimizer.update(grads, opt_state, params, lr)
         metrics = dict(metrics, grad_norm=gnorm, lr=lr)
         return {"params": new_params, "opt_state": new_opt, "step": step + 1}, metrics
 
@@ -140,9 +141,10 @@ def _build_compressed_train_step(arch, optimizer, rt, lr_schedule, grad_clip, gc
             bits=gc.bits, scale_axis=gc.scale_axis, pspec_tree=pspec_tree,
         )
         metrics = jax.tree.map(lambda m: jnp.mean(m, axis=0), metrics)
-        grads, gnorm = clip_by_global_norm(grads, grad_clip)
-        lr = lr_schedule(step)
-        new_params, new_opt = optimizer.update(grads, opt_state, params, lr)
+        with jax.named_scope("optimizer"):
+            grads, gnorm = clip_by_global_norm(grads, grad_clip)
+            lr = lr_schedule(step)
+            new_params, new_opt = optimizer.update(grads, opt_state, params, lr)
         metrics = dict(metrics, grad_norm=gnorm, lr=lr)
         return {
             "params": new_params,

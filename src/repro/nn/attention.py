@@ -386,15 +386,16 @@ def apply_attention(
         assert view is not None, "paged attention cache needs a block-table view"
         bt = view["bt"]
         quant = "kps" in cache  # int8 pools carry per-slot scale pools
-        if quant:
-            kp_new, kps_new = _paged_write_q8(cache["kp"], cache["kps"], kh, bt, positions)
-            vp_new, vps_new = _paged_write_q8(cache["vp"], cache["vps"], vh, bt, positions)
-            new_cache = {"kp": kp_new, "kps": kps_new, "vp": vp_new, "vps": vps_new}
-        else:
-            new_cache = {
-                "kp": _paged_write(cache["kp"], kh, bt, positions),
-                "vp": _paged_write(cache["vp"], vh, bt, positions),
-            }
+        with jax.named_scope("kv_write"):
+            if quant:
+                kp_new, kps_new = _paged_write_q8(cache["kp"], cache["kps"], kh, bt, positions)
+                vp_new, vps_new = _paged_write_q8(cache["vp"], cache["vps"], vh, bt, positions)
+                new_cache = {"kp": kp_new, "kps": kps_new, "vp": vp_new, "vps": vps_new}
+            else:
+                new_cache = {
+                    "kp": _paged_write(cache["kp"], kh, bt, positions),
+                    "vp": _paged_write(cache["vp"], vh, bt, positions),
+                }
         # int8 and packed-int4 pools both ride the kernel (it detects the
         # byte width from the pool dtype); windowed decode is covered via
         # the kernel's window mask
@@ -494,17 +495,19 @@ def _apply_mla(
         assert view is not None, "paged MLA cache needs a block-table view"
         bt = view["bt"]
         if "ckvs" in cache:  # int8 latent pools, per-token fp32 scales
-            ckvp_new, ckvs_new = _paged_write_q8(cache["ckvp"], cache["ckvs"], ckv, bt, positions)
-            kpep_new, kpes_new = _paged_write_q8(cache["kpep"], cache["kpes"], kpe, bt, positions)
+            with jax.named_scope("kv_write"):
+                ckvp_new, ckvs_new = _paged_write_q8(cache["ckvp"], cache["ckvs"], ckv, bt, positions)
+                kpep_new, kpes_new = _paged_write_q8(cache["kpep"], cache["kpes"], kpe, bt, positions)
             cache = {"ckvp": ckvp_new, "ckvs": ckvs_new, "kpep": kpep_new, "kpes": kpes_new}
             if not use_kernel:
                 ckv_all = _paged_gather_deq(cache["ckvp"], cache["ckvs"], bt)
                 kpe_all = _paged_gather_deq(cache["kpep"], cache["kpes"], bt)
         else:
-            cache = {
-                "ckvp": _paged_write(cache["ckvp"], ckv, bt, positions),
-                "kpep": _paged_write(cache["kpep"], kpe, bt, positions),
-            }
+            with jax.named_scope("kv_write"):
+                cache = {
+                    "ckvp": _paged_write(cache["ckvp"], ckv, bt, positions),
+                    "kpep": _paged_write(cache["kpep"], kpe, bt, positions),
+                }
             if not use_kernel:
                 ckv_all = _paged_gather(cache["ckvp"], bt)
                 kpe_all = _paged_gather(cache["kpep"], bt)

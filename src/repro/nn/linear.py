@@ -445,10 +445,11 @@ def _apply_linear_int8(
     else:
         # unchained int forward: the act-quant is its own dispatch
         _record("standalone", site)
-        xq, x_scale = act_quant_int(
-            {"log2_scale": params["aq"]["log2_scale"]},
-            x.astype(jnp.float32), N, signed=input_signed,
-        )
+        with jax.named_scope("act_quant"):
+            xq, x_scale = act_quant_int(
+                {"log2_scale": params["aq"]["log2_scale"]},
+                x.astype(jnp.float32), N, signed=input_signed,
+            )
         _probe_acc(site, xq, params["q8"], in_bits=N, in_signed=input_signed,
                    acc_bits=kw["acc_bits"], spill_int16=kw["spill_int16"])
         if not input_signed and N == 8:
@@ -526,10 +527,12 @@ def apply_linear(
         _record("fallback", site)
         x = _int_act_to_fp(x, compute_dtype)
     if cfg.mode != "none" and "aq" in params:
-        x = apply_act_quant(
-            {"log2_scale": params["aq"]["log2_scale"]}, x, N, signed=input_signed
-        )
-    w = _quant_weights(params, cfg, boundary, input_signed).astype(compute_dtype)
+        with jax.named_scope("act_quant"):
+            x = apply_act_quant(
+                {"log2_scale": params["aq"]["log2_scale"]}, x, N, signed=input_signed
+            )
+    with jax.named_scope("a2q_weight_quant"):
+        w = _quant_weights(params, cfg, boundary, input_signed).astype(compute_dtype)
     y = jnp.dot(x.astype(compute_dtype), w)
     if "b" in params:
         y = y + params["b"].astype(compute_dtype)
@@ -541,8 +544,9 @@ def linear_penalty(params: dict, cfg: QuantConfig, boundary: bool, input_signed:
     if cfg.mode != "a2q" or "t" not in params:
         return jnp.zeros((), jnp.float32)
     _, N = _bits(cfg, boundary)
-    T = a2q_norm_cap(params["d"], cfg.acc_bits, N, input_signed)
-    return jnp.sum(jnp.maximum(params["t"] - T, 0.0))
+    with jax.named_scope("a2q_weight_quant"):
+        T = a2q_norm_cap(params["d"], cfg.acc_bits, N, input_signed)
+        return jnp.sum(jnp.maximum(params["t"] - T, 0.0))
 
 
 def deploy_linear(params: dict, cfg: QuantConfig, *, boundary: bool = False, input_signed: bool = True) -> dict:
@@ -626,10 +630,12 @@ def apply_conv(
     """NHWC convolution with the same quant pipeline as apply_linear."""
     M, N = _bits(cfg, boundary)
     if cfg.mode != "none" and "aq" in params:
-        x = apply_act_quant(
-            {"log2_scale": params["aq"]["log2_scale"]}, x, N, signed=input_signed
-        )
-    w = _quant_weights(params, cfg, boundary, input_signed).astype(compute_dtype)
+        with jax.named_scope("act_quant"):
+            x = apply_act_quant(
+                {"log2_scale": params["aq"]["log2_scale"]}, x, N, signed=input_signed
+            )
+    with jax.named_scope("a2q_weight_quant"):
+        w = _quant_weights(params, cfg, boundary, input_signed).astype(compute_dtype)
     y = jax.lax.conv_general_dilated(
         x.astype(compute_dtype),
         w,

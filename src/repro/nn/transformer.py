@@ -148,32 +148,29 @@ def _apply_block(
     new_cache: dict = {}
     if s.kind in ("attn_mlp", "moe"):
         h = norm(p["ln1"], x)
-        attn_out, c = apply_attention(
-            p["attn"], h, s.attn, q, positions, (cache or {}).get("attn"),
-            q_chunk=arch.attn_q_chunk, compute_dtype=cd, mla_absorb=mla_absorb,
-            view=view, decode_kernel=decode_kernel, int_forward=int_forward,
-            int_chain=int_chain,
-        )
+        with jax.named_scope("attention"):
+            attn_out, c = apply_attention(
+                p["attn"], h, s.attn, q, positions, (cache or {}).get("attn"),
+                q_chunk=arch.attn_q_chunk, compute_dtype=cd, mla_absorb=mla_absorb,
+                view=view, decode_kernel=decode_kernel, int_forward=int_forward,
+                int_chain=int_chain,
+            )
         if c is not None:
             new_cache["attn"] = c
+
+        def ffn_of(hx):
+            with jax.named_scope("mlp"):
+                if s.kind == "moe":
+                    return apply_moe(p["moe"], hx, s.moe, q, ep_axis=ep_axis, mesh=mesh,
+                                     compute_dtype=cd, int_forward=int_forward,
+                                     int_chain=int_chain)
+                return _apply_mlp(p["mlp"], hx, q, cd, int_forward, int_chain)
+
         if s.parallel_block:
-            if s.kind == "moe":
-                ffn = apply_moe(p["moe"], h, s.moe, q, ep_axis=ep_axis, mesh=mesh,
-                                compute_dtype=cd, int_forward=int_forward,
-                                int_chain=int_chain)
-            else:
-                ffn = _apply_mlp(p["mlp"], h, q, cd, int_forward, int_chain)
-            x = x + attn_out + ffn
+            x = x + attn_out + ffn_of(h)
         else:
             x = x + attn_out
-            h2 = norm(p["ln2"], x)
-            if s.kind == "moe":
-                ffn = apply_moe(p["moe"], h2, s.moe, q, ep_axis=ep_axis, mesh=mesh,
-                                compute_dtype=cd, int_forward=int_forward,
-                                int_chain=int_chain)
-            else:
-                ffn = _apply_mlp(p["mlp"], h2, q, cd, int_forward, int_chain)
-            x = x + ffn
+            x = x + ffn_of(norm(p["ln2"], x))
     elif s.kind == "rwkv6":
         h = norm(p["ln1"], x)
         y, c = apply_rwkv6_timemix(p["tm"], h, s.ssm, q, (cache or {}).get("tm"), compute_dtype=cd, int_forward=int_forward, int_chain=int_chain)
@@ -187,19 +184,23 @@ def _apply_block(
         x = x + y2
     elif s.kind == "hymba":
         h = norm(p["ln1"], x)
-        attn_out, c = apply_attention(
-            p["attn"], h, s.attn, q, positions, (cache or {}).get("attn"),
-            q_chunk=arch.attn_q_chunk, compute_dtype=cd,
-            view=view, decode_kernel=decode_kernel, int_forward=int_forward,
-            int_chain=int_chain,
-        )
+        with jax.named_scope("attention"):
+            attn_out, c = apply_attention(
+                p["attn"], h, s.attn, q, positions, (cache or {}).get("attn"),
+                q_chunk=arch.attn_q_chunk, compute_dtype=cd,
+                view=view, decode_kernel=decode_kernel, int_forward=int_forward,
+                int_chain=int_chain,
+            )
         if c is not None:
             new_cache["attn"] = c
         m_out, cm = apply_mamba_heads(p["mamba"], h, s.ssm, q, (cache or {}).get("mamba"), compute_dtype=cd, int_forward=int_forward, int_chain=int_chain)
         if cm is not None:
             new_cache["mamba"] = cm
         x = x + 0.5 * (attn_out + m_out)
-        x = x + _apply_mlp(p["mlp"], norm(p["ln2"], x), q, cd, int_forward, int_chain)
+        h2 = norm(p["ln2"], x)
+        with jax.named_scope("mlp"):
+            ffn = _apply_mlp(p["mlp"], h2, q, cd, int_forward, int_chain)
+        x = x + ffn
     else:
         raise ValueError(s.kind)
 

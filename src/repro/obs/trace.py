@@ -1,5 +1,6 @@
 """Request-span tracing: context-manager spans with monotonic timestamps,
-exported as Chrome trace-event JSON (Perfetto-loadable).
+exported as Chrome trace-event JSON (Perfetto-loadable) and mirrored as
+profiler annotations.
 
 Design constraints, in priority order:
 
@@ -19,6 +20,14 @@ Design constraints, in priority order:
    stats and request latency timestamps already use, so span durations and
    ``stats["decode_s"]`` agree to the microsecond and a trace can be lined
    up against a metrics snapshot from the same run.
+4. **Also on the profiler's clock.**  An enabled span enters a
+   ``jax.profiler.TraceAnnotation`` of the same name for its extent, with
+   its args set as the annotation's stats at exit (args a span fills in
+   while it runs are included).  Under an active profiler trace the span
+   lands on the host plane beside the device's program runs, so a device
+   idle gap can be put down to the program span over it; with no profiler
+   active the annotation costs a fraction of a microsecond.  Instants make
+   no annotation.
 
 The export format is the Chrome trace-event JSON object form::
 
@@ -39,6 +48,8 @@ from __future__ import annotations
 import json
 import time
 from typing import Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["Tracer", "Span", "NULL_SPAN"]
 
@@ -64,9 +75,11 @@ NULL_SPAN = _NullSpan()
 
 
 class Span:
-    """One live span; append-on-exit keeps ``__enter__`` to a clock read."""
+    """One live span; append-on-exit keeps ``__enter__`` to a clock read and
+    an annotation.  The annotation opens before the clock read and closes
+    after it, so its cost stays out of the recorded duration."""
 
-    __slots__ = ("_tracer", "name", "args", "t0", "dur_s")
+    __slots__ = ("_tracer", "name", "args", "t0", "dur_s", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: Optional[dict]):
         self._tracer = tracer
@@ -76,11 +89,16 @@ class Span:
         self.dur_s = 0.0
 
     def __enter__(self):
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         self.dur_s = time.perf_counter() - self.t0
+        if self.args:
+            self._ann.set_metadata(**self.args)
+        self._ann.__exit__(*exc)
         self._tracer.events.append(("X", self.name, self.t0, self.dur_s, self.args))
         return False
 
@@ -104,8 +122,9 @@ class Tracer:
     # -- recording ----------------------------------------------------------
 
     def span(self, name: str, args: Optional[dict] = None):
-        """Context manager timing one region.  Disabled tracers return the
-        shared null span (identity-stable; zero allocation)."""
+        """Context manager timing one region, on ``perf_counter`` and as a
+        profiler annotation.  Disabled tracers return the shared null span
+        (identity-stable; zero allocation, no clock read, no annotation)."""
         if not self.enabled:
             return NULL_SPAN
         return Span(self, name, args)

@@ -648,8 +648,9 @@ class PagedServeEngine(_StatsMixin):
                 self.params_struct(params), self.arch, tokens=tok[:, None],
                 cache=cache, start_pos=pos, rt=self.rt,
             )
-            nxt = sample_tokens(logits[:, 0], self.sample_cfg, k)
-            marg = self._greedy_margin(logits[:, 0])
+            with jax.named_scope("sample"):
+                nxt = sample_tokens(logits[:, 0], self.sample_cfg, k)
+                marg = self._greedy_margin(logits[:, 0])
             emitted = act
             adv = act.astype(jnp.int32)
             pos2 = pos + adv
@@ -1019,40 +1020,46 @@ class PagedServeEngine(_StatsMixin):
             return 0
         tr = self.obs.trace
         N = self.decode_steps
-        tok_in = np.zeros((self.batch,), np.int32)
-        active = np.zeros((self.batch,), bool)
-        rem = np.zeros((self.batch,), np.int32)
-        eos = np.full((self.batch,), -1, np.int32)  # -1: token ids are >= 0
         with tr.span("cow_preflight", {"live": len(live)}):
             for i in live:
                 req = self.sched.slots[i]
-                tok_in[i] = req.last_token
-                active[i] = True
-                rem[i] = req.max_new - len(req.generated)
-                if req.eos_id is not None:
-                    eos[i] = req.eos_id
                 lo = int(self.cache.lens[i])
-                self.cache.ensure_writable(i, lo, lo + min(N, int(rem[i])))
+                self.cache.ensure_writable(i, lo, lo + min(N, req.max_new - len(req.generated)))
         t0 = time.perf_counter()
         with tr.span("decode_megastep", {"live": len(live), "steps": N}):
-            toks, margs, emitted, pools = self._megadecode(
-                self.params, jnp.asarray(tok_in), self.cache.pools, self.cache.bt(),
-                jnp.asarray(self.cache.lens.copy()), jnp.asarray(active),
-                jnp.asarray(rem), jnp.asarray(eos), self._next_key(),
-            )
+            with tr.span("megastep_args"):
+                tok_in = np.zeros((self.batch,), np.int32)
+                active = np.zeros((self.batch,), bool)
+                rem = np.zeros((self.batch,), np.int32)
+                eos = np.full((self.batch,), -1, np.int32)  # -1: token ids are >= 0
+                for i in live:
+                    req = self.sched.slots[i]
+                    tok_in[i] = req.last_token
+                    active[i] = True
+                    rem[i] = req.max_new - len(req.generated)
+                    if req.eos_id is not None:
+                        eos[i] = req.eos_id
+                args = (jnp.asarray(tok_in), self.cache.pools, self.cache.bt(),
+                        jnp.asarray(self.cache.lens.copy()), jnp.asarray(active),
+                        jnp.asarray(rem), jnp.asarray(eos), self._next_key())
+            toks, margs, emitted, pools = self._megadecode(self.params, *args)
             self.cache.pools = pools
-            out, marg, em = (np.asarray(a) for a in jax.device_get((toks, margs, emitted)))
+            with tr.span("megastep_sync"):
+                out, marg, em = (np.asarray(a) for a in jax.device_get((toks, margs, emitted)))
         dt = time.perf_counter() - t0
         total = 0
-        for j in range(N):
-            for i in live:
-                if not em[i, j]:
-                    continue
-                total += 1
-                self.cache.lens[i] += 1
-                self.sched.slots[i].margins.append(float(marg[i, j]))
-                if self.sched.record_token(i, int(out[i, j])):
-                    self._release_slot(i)
+        replayed = {"tokens": 0, "released": 0}
+        with tr.span("replay", replayed):
+            for j in range(N):
+                for i in live:
+                    if not em[i, j]:
+                        continue
+                    total += 1
+                    self.cache.lens[i] += 1
+                    self.sched.slots[i].margins.append(float(marg[i, j]))
+                    if self.sched.record_token(i, int(out[i, j])):
+                        self._release_slot(i)
+            replayed.update(tokens=total, released=len(live) - len(self.sched.live))
         self.stats["decode_s"] += dt
         self.stats["decode_tokens"] += total
         self.stats["decode_dispatches"] += 1
@@ -1068,14 +1075,19 @@ class PagedServeEngine(_StatsMixin):
 
     def step(self) -> int:
         """Admit what fits, then advance one decode round."""
-        admitted = self.sched.admissions(self._admission_gate())
-        if self.sched.lockstep:
-            if admitted:
-                self._admit_group(admitted)
-        else:
-            for slot, req in admitted:
-                self._admit(slot, req)
-        n = self._advance()
+        tr = self.obs.trace
+        with tr.span("engine_step"):
+            admission = {"admitted": 0, "queued": 0}
+            with tr.span("admission", admission):
+                admitted = self.sched.admissions(self._admission_gate())
+                admission.update(admitted=len(admitted), queued=len(self.sched.queue))
+            if self.sched.lockstep:
+                if admitted:
+                    self._admit_group(admitted)
+            else:
+                for slot, req in admitted:
+                    self._admit(slot, req)
+            n = self._advance()
         if n == 0 and not admitted and self.sched.queue:
             raise RuntimeError("scheduler stalled: queued work but nothing admittable")
         return n

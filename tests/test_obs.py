@@ -2,6 +2,11 @@
 and the cluster-wide snapshot merge."""
 
 import json
+import os
+import pathlib
+import re
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -10,6 +15,7 @@ import pytest
 
 from repro.configs import get_arch, reduced
 from repro.configs.base import QuantConfig
+from repro.kernels import ops
 from repro.models.lm import Runtime, init_lm
 from repro.nn.module import unbox
 from repro.obs import (
@@ -42,7 +48,13 @@ def test_span_nesting_child_before_parent():
     assert child[1] + child[2] <= parent[1] + parent[2] + 1e-9
 
 
-def test_disabled_tracer_is_null_span_identity():
+def test_disabled_tracer_is_null_span_identity(monkeypatch):
+    import repro.obs.trace as trace_mod
+
+    def no_annotation(*a, **k):
+        raise AssertionError("a disabled tracer entered a profiler annotation")
+
+    monkeypatch.setattr(trace_mod, "TraceAnnotation", no_annotation)
     tr = Tracer(enabled=False)
     s1 = tr.span("a", {"k": 1})
     s2 = tr.span("b")
@@ -70,6 +82,63 @@ def test_chrome_export_schema(tmp_path):
     assert x["dur"] >= 0 and x["ts"] >= 0  # microseconds from tracer origin
     assert i["s"] == "t" and "dur" not in i
     assert all(e["pid"] == 3 and e["tid"] == 7 for e in evs)
+
+
+# spans on the profiler's clock: run in a child process, so that no profiler
+# trace another test left running in this worker can get in the way
+_PROFILED = r"""
+import glob, json, sys
+import jax
+from jax.profiler import ProfileData
+from repro.obs import Tracer
+
+tr = Tracer()
+d = sys.argv[1]
+jax.profiler.start_trace(d)
+with tr.span("decode_megastep", {"live": 3, "steps": 8}):
+    with tr.span("megastep_sync"):
+        pass
+filled = {"tokens": 0, "released": 0}
+with tr.span("replay", filled):
+    filled.update(tokens=5, released=1)
+tr.instant("emit", {"uid": 1})
+jax.profiler.stop_trace()
+pd = ProfileData.from_file(glob.glob(d + "/**/*.xplane.pb", recursive=True)[0])
+out = [[plane.name, line.name, ev.name, dict(ev.stats)]
+       for plane in pd.planes for line in plane.lines for ev in line.events
+       if ev.name in ("decode_megastep", "megastep_sync", "replay", "emit")]
+print(json.dumps({"profiled": out, "recorded": [e[:2] + e[4:] for e in tr.events]}))
+"""
+
+
+@pytest.fixture(scope="module")
+def profiled_spans(tmp_path_factory):
+    src = str(pathlib.Path(__file__).resolve().parent.parent / "src")
+    env = dict(os.environ, PYTHONPATH=src, JAX_PLATFORMS="cpu")
+    res = subprocess.run([sys.executable, "-c", _PROFILED, str(tmp_path_factory.mktemp("profile"))],
+                         capture_output=True, text=True, env=env, timeout=300)
+    assert res.returncode == 0, res.stderr[-2000:]
+    return json.loads(res.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("name,stats", [
+    ("decode_megastep", {"live": 3, "steps": 8}),
+    ("megastep_sync", {}),
+    ("replay", {"tokens": 5, "released": 1}),  # args filled in while the span ran
+])
+def test_enabled_span_lands_on_the_profiler_trace(profiled_spans, name, stats):
+    hits = [e for e in profiled_spans["profiled"] if e[2] == name]
+    assert len(hits) == 1, profiled_spans["profiled"]
+    plane, line, _, got = hits[0]
+    assert plane.startswith("/host") and line.startswith("python")
+    assert got == stats
+    # the perf_counter record is the one it always was
+    assert ["X", name, stats or None] in profiled_spans["recorded"]
+
+
+def test_instant_makes_no_annotation(profiled_spans):
+    assert not [e for e in profiled_spans["profiled"] if e[2] == "emit"]
+    assert ["i", "emit", {"uid": 1}] in profiled_spans["recorded"]
 
 
 def test_tracer_clear_resets_origin_and_events():
@@ -237,6 +306,27 @@ def test_traced_engine_spans_and_parity():
         assert "uid" in args and "slot" in args
 
 
+def test_traced_megastep_host_loop_spans():
+    arch = reduced(get_arch("yi-6b"))
+    e = PagedServeEngine(arch, _params(arch), obs=Obs(trace=True), decode_steps=2, **KW)
+    prompts = _prompts(arch, n=3)
+    e.generate(prompts, max_new=5)
+    tr = e.obs.trace
+    steps = tr.spans("engine_step")
+    mega = tr.spans("decode_megastep")
+    assert steps and mega
+    # every host-loop span lies inside an engine step, and the argument
+    # uploads and the sync inside a megastep
+    inside = lambda sp, outer: any(o[1] <= sp[1] and sp[1] + sp[2] <= o[1] + o[2] for o in outer)
+    for name in ("admission", "cow_preflight", "decode_megastep", "replay"):
+        assert tr.spans(name) and all(inside(sp, steps) for sp in tr.spans(name)), name
+    for name in ("megastep_args", "megastep_sync"):
+        assert len(tr.spans(name)) == len(mega) and all(inside(sp, mega) for sp in tr.spans(name))
+    assert sum(a["admitted"] for *_, a in tr.spans("admission")) == len(prompts)
+    assert sum(a["tokens"] for *_, a in tr.spans("replay")) == e.stats["decode_tokens"]
+    assert sum(a["released"] for *_, a in tr.spans("replay")) == len(prompts)
+
+
 def test_untraced_engine_records_no_events():
     arch = reduced(get_arch("yi-6b"))
     e = PagedServeEngine(arch, _params(arch), **KW)
@@ -295,3 +385,92 @@ def test_replica_merge_equals_fleet():
     assert sorted(lat) == sorted(s1["request_latency_s"]["values"]
                                  + s2["request_latency_s"]["values"])
     assert percentile(lat, 99) == max(lat)
+
+
+# -- names on device work ------------------------------------------------------
+
+_f32, _bf16, _i8, _i32 = jnp.float32, jnp.bfloat16, jnp.int8, jnp.int32
+_B, _KV, _G, _DH, _BS, _NB, _MB, _H, _R, _P = 2, 2, 2, 128, 16, 9, 4, 8, 128, 64
+# kernel name -> (call, operand shapes, lowers for TPU).  The RWKV-6 scan's
+# transposed-operand dot does not lower for TPU in this JAX; its name is read
+# from the traced program only.
+_KERNELS = {
+    "int_matmul": (lambda x, w, s: ops.int_matmul(x, w, scale=s, interpret=False),
+                   [((8, 256), _i8), ((256, 256), _i8), ((256,), _f32)], True),
+    "a2q_quantize": (lambda v, t, d: ops.a2q_quantize(
+        v, t, d, weight_bits=8, acc_bits=16, input_bits=8, input_signed=True, interpret=False),
+        [((256, 256), _f32), ((256,), _f32), ((256,), _f32)], True),
+    "flash_attention": (lambda q, k, v: ops.flash_attention(q, k, v, interpret=False),
+                        [((1, 2, 128, 128), _bf16)] * 3, True),
+    "paged_attention": (lambda q, kp, vp, bt, ln: ops.paged_attention(
+        q, kp, vp, bt, ln, interpret=False),
+        [((_B, _KV * _G, _DH), _bf16), ((_NB, _BS, _KV, _DH), _bf16),
+         ((_NB, _BS, _KV, _DH), _bf16), ((_B, _MB), _i32), ((_B,), _i32)], True),
+    "paged_mla_attention": (lambda ql, qp, c, k, bt, ln: ops.paged_mla_attention(
+        ql, qp, c, k, bt, ln, scale=0.1, interpret=False),
+        [((_B, _H, _R), _f32), ((_B, _H, _P), _f32), ((_NB, _BS, _R), _bf16),
+         ((_NB, _BS, _P), _bf16), ((_B, _MB), _i32), ((_B,), _i32)], True),
+    "rwkv6_scan": (lambda r, k, v, w, u: ops.rwkv6_scan(r, k, v, w, u, interpret=False),
+                   [((1, 1, 64, 64), _f32)] * 4 + [((1, 64), _f32)], False),
+}
+
+
+def _pallas_names(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params.get("name")
+        for v in eqn.params.values():
+            sub = getattr(v, "jaxpr", v)
+            if hasattr(sub, "eqns"):
+                yield from _pallas_names(sub)
+
+
+@pytest.mark.parametrize("name", sorted(_KERNELS))
+def test_every_pallas_call_is_named(name):
+    kernels = pathlib.Path(ops.__file__).parent
+    calls = sum(f.read_text().count("pl.pallas_call(") for f in kernels.glob("*.py"))
+    assert calls == len(_KERNELS), "a pallas_call without a case here"
+    fn, shapes, tpu = _KERNELS[name]
+    args = [jax.ShapeDtypeStruct(s, dt) for s, dt in shapes]
+    assert list(_pallas_names(jax.make_jaxpr(fn)(*args).jaxpr)) == [name]
+    if tpu:
+        text = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+        assert re.findall(r'kernel_name = "([^"]*)"', text) == [name]
+
+
+@pytest.fixture(scope="module")
+def scoped_programs():
+    """The lowered text, with locations, of a jitted A2Q train step and of the
+    decode megastep of reduced models."""
+    from repro.models.steps import build_train_step
+    from repro.optim.optimizers import adamw
+
+    arch = reduced(get_arch("smollm-135m"))
+    params = _params(arch)
+    opt = adamw()
+    state = {"params": params, "opt_state": opt.init(params), "step": jnp.zeros((), jnp.int32)}
+    toks = jnp.zeros((2, 16), jnp.int32)
+    train = jax.jit(build_train_step(arch, opt, Runtime())).lower(
+        state, {"tokens": toks, "targets": toks}).as_text(debug_info=True)
+    sarch = reduced(get_arch("yi-6b"))
+    e = PagedServeEngine(sarch, _params(sarch), decode_steps=2, **KW)
+    b = e.batch
+    mega = e._megadecode.lower(
+        e.params, jnp.zeros((b,), jnp.int32), e.cache.pools, e.cache.bt(),
+        jnp.zeros((b,), jnp.int32), jnp.ones((b,), bool), jnp.full((b,), 4, jnp.int32),
+        jnp.full((b,), -1, jnp.int32), jax.random.PRNGKey(0)).as_text(debug_info=True)
+    return {"train": train, "megastep": mega}
+
+
+@pytest.mark.parametrize("program,scope", [
+    ("train", "attention"), ("train", "mlp"), ("train", "a2q_weight_quant"),
+    ("train", "act_quant"), ("train", "embed"), ("train", "head"), ("train", "loss"),
+    ("train", "optimizer"),
+    ("megastep", "attention"), ("megastep", "kv_write"), ("megastep", "mlp"),
+    ("megastep", "sample"),
+])
+def test_named_scopes_in_the_lowered_program(scoped_programs, program, scope):
+    # a scope is a path component of an operation's location; transforms
+    # wrap it (``transpose(jvp(attention))``) in the backward pass
+    pat = re.compile(r'loc\("(?:[^"]*/)?(?:\w+\()*' + scope + r'\)*/[^"]*"')
+    assert pat.search(scoped_programs[program]), (program, scope)
