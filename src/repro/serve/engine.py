@@ -46,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig, QuantConfig
+from repro.kernels.paged_attention import compute_block_pages, kv_block_range
 from repro.models.lm import Runtime, apply_lm, init_cache, init_lm
 from repro.nn.linear import deploy_linear
 from repro.nn.module import unbox
@@ -556,6 +557,20 @@ class PagedServeEngine(_StatsMixin):
         self._prefill = jax.jit(self._prefill_fn, donate_argnums=(2,))
         self._decode = jax.jit(self._decode_fn, donate_argnums=(2,))
         self._megadecode = jax.jit(self._megastep_fn, donate_argnums=(2,))
+        self._kv_walk = self._decode_kernel_walk() if self.rt.decode_kernel else None
+
+    def _decode_kernel_walk(self) -> Optional[tuple]:
+        """``(tokens a compute block, grid blocks)`` of the paged decode
+        kernel over this cache's GQA pools, or None where no stack pages
+        GQA K/V (the kernel then never runs)."""
+        for c in self.cache.pools.values():
+            kp = c.get("attn", {}).get("kp")
+            if kp is not None:
+                _, _, bs, kv, width = kp.shape
+                mb = self.cache.max_blocks_per_seq
+                pages = compute_block_pages(bs, kv, width, kp.dtype, mb, "kps" in c["attn"])
+                return pages * bs, self.batch * -(-mb // pages)
+        return None
 
     def params_struct(self, params):
         return params
@@ -1026,7 +1041,15 @@ class PagedServeEngine(_StatsMixin):
                 lo = int(self.cache.lens[i])
                 self.cache.ensure_writable(i, lo, lo + min(N, req.max_new - len(req.generated)))
         t0 = time.perf_counter()
-        with tr.span("decode_megastep", {"live": len(live), "steps": N}):
+        mega = {"live": len(live), "steps": N}
+        if self._kv_walk and tr.enabled:
+            # the paged decode kernel's walk at the first tick: every row at
+            # its length with this tick's token (a free slot walks its one
+            # trash block), against the blocks its grid holds
+            tokens, grid = self._kv_walk
+            first, end = kv_block_range(self.cache.lens + 1, tokens)
+            mega.update(kv_blocks_walked=int((end - first).sum()), kv_blocks_grid=grid)
+        with tr.span("decode_megastep", mega):
             with tr.span("megastep_args"):
                 tok_in = np.zeros((self.batch,), np.int32)
                 active = np.zeros((self.batch,), bool)

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels.paged_attention import compute_block_pages, kv_block_range
 
 RNG = np.random.default_rng(0)
 
@@ -508,6 +509,123 @@ def test_paged_attention_q4_requires_scales():
     q = jnp.asarray(RNG.normal(size=(B, H, Dh)), jnp.float32)
     with pytest.raises(ValueError):
         ops.paged_attention(q, kq, vq, bt, ln)
+
+
+# ---------------------------------------------------------------------------
+# the decode kernel's walk: compute blocks of several pages, live pages only
+# ---------------------------------------------------------------------------
+
+_WALK = dict(B=8, H=8, KV=4, Dh=16, bs=16, MB=20)  # MB not a multiple of the block
+
+
+def _walk_lengths(bs, pages, mb):
+    """Every boundary of a page and of a compute block, in one batch."""
+    return [0, 1, bs - 1, bs, pages * bs - 1, pages * bs, pages * bs + 1, mb * bs]
+
+
+def _walk_pools(kv, rng, NB, bs, KV, Dh):
+    if kv == "fp32":
+        kp = jnp.asarray(rng.normal(size=(NB, bs, KV, Dh)), jnp.float32)
+        vp = jnp.asarray(rng.normal(size=(NB, bs, KV, Dh)), jnp.float32)
+        return kp, vp, None, None
+    return (_q8_pools if kv == "int8" else _q4_pools)(rng, NB, bs, KV, Dh)
+
+
+def _walk_ref(kv, q, kp, vp, ks, vs, bt, ln, window):
+    if kv == "fp32":
+        return ref.ref_paged_attention(q, kp, vp, bt, ln, window=window)
+    oracle = ref.ref_paged_attention_q8 if kv == "int8" else ref.ref_paged_attention_q4
+    return oracle(q, kp, vp, ks, vs, bt, ln, window=window)
+
+
+@pytest.mark.parametrize("KV", [4, 2])
+@pytest.mark.parametrize("window", [None, 40])
+@pytest.mark.parametrize("kv", ["fp32", "int8", "int4"])
+def test_paged_attention_walk_boundaries(kv, window, KV):
+    """Rows at every page and compute-block boundary, in one batch, over a
+    table whose width is not a multiple of the block: the kernel walks only
+    each row's live blocks (with ``window``, not those wholly before it)
+    and matches the oracle.  KV 4 reads int8 codes as packed words."""
+    B, H, Dh, bs, MB = (_WALK[k] for k in ("B", "H", "Dh", "bs", "MB"))
+    width, dtype = (Dh // 2, jnp.uint8) if kv == "int4" else (Dh, {"fp32": jnp.float32}.get(kv, jnp.int8))
+    pages = compute_block_pages(bs, KV, width, dtype, MB, kv != "fp32")
+    assert 1 < pages < MB and MB % pages
+    lens = _walk_lengths(bs, pages, MB)
+    NB = 1 + sum(-(-n // bs) for n in lens)
+    rng = np.random.default_rng(31)
+    kp, vp, ks, vs = _walk_pools(kv, rng, NB, bs, KV, Dh)
+    _, _, bt, ln = _paged_setup(B, KV, Dh, NB, bs, MB, lens)
+    q = jnp.asarray(rng.normal(size=(B, H, Dh)), jnp.float32)
+    got = ops.paged_attention(q, kp, vp, bt, ln, kps=ks, vps=vs, window=window)
+    want = _walk_ref(kv, q, kp, vp, ks, vs, bt, ln, window)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    assert np.abs(np.asarray(got)[0]).max() == 0.0  # the zero-length row
+
+
+@pytest.mark.parametrize("kv", ["fp32", "int8"])
+def test_paged_attention_never_reads_dead_pages(kv):
+    """Every pool page that no row's live prefix references holds NaN (fp
+    keys and values; int8 scales), the trash block too, and every dead
+    table entry points at one.  One row's last live page opens its compute
+    block, so the block's later pages are dead.  A page the kernel read
+    would turn its row NaN (``0 * NaN`` in the PV product)."""
+    B, H, KV, Dh, bs, MB = 4, 8, 4, 16, 16, 20
+    pages = compute_block_pages(bs, KV, Dh, {"fp32": jnp.float32, "int8": jnp.int8}[kv], MB, kv == "int8")
+    lens = [pages * bs + 3, 0, 5, MB * bs]
+    NB = 2 + sum(-(-n // bs) for n in lens)
+    rng = np.random.default_rng(37)
+    kp, vp, ks, vs = _walk_pools(kv, rng, NB, bs, KV, Dh)
+    _, _, bt, ln = _paged_setup(B, KV, Dh, NB, bs, MB, lens)
+    q = jnp.asarray(rng.normal(size=(B, H, Dh)), jnp.float32)
+    want = _walk_ref(kv, q, kp, vp, ks, vs, bt, ln, None)
+    live = np.zeros(NB, bool)
+    for b, n in enumerate(lens):
+        live[np.asarray(bt)[b, : -(-n // bs)]] = True
+    dead = jnp.asarray(~live)
+    assert not live[0] and not live[NB - 1]
+    bt_dead = np.asarray(bt).copy()
+    for b, n in enumerate(lens):
+        bt_dead[b, -(-n // bs):] = NB - 1
+    nan_where = lambda x: jnp.where(dead.reshape(-1, *[1] * (x.ndim - 1)), jnp.nan, x)
+    if kv == "fp32":
+        kp, vp = nan_where(kp), nan_where(vp)
+    else:
+        ks, vs = nan_where(ks), nan_where(vs)
+    got = np.asarray(ops.paged_attention(q, kp, vp, jnp.asarray(bt_dead), ln, kps=ks, vps=vs))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, np.asarray(want), atol=2e-5)
+
+
+@pytest.mark.parametrize("window", [None, 1, 17, 300])
+def test_kv_block_range_boundaries(window):
+    """The walk's bounds against a count of the blocks that hold a key the
+    row attends, at every boundary length, for ints, numpy and traced
+    lengths alike (the engine's counter and the kernel's loop bounds)."""
+    bs, pages, mb = 16, 16, 20
+    tokens = pages * bs
+    lens = _walk_lengths(bs, pages, mb)
+    want = []
+    for n in lens:
+        keys = range(max(n - window, 0) if window else 0, n)
+        blocks = sorted({k // tokens for k in keys})
+        want.append((blocks[0], blocks[-1] + 1) if blocks else None)
+    for n, w in zip(lens, want):
+        first, end = kv_block_range(n, tokens, window)
+        assert (end <= first) if w is None else ((first, end) == w), (n, first, end, w)
+    first, end = kv_block_range(np.asarray(lens), tokens, window)
+    traced = jax.jit(lambda x: kv_block_range(x, tokens, window))(jnp.asarray(lens, jnp.int32))
+    np.testing.assert_array_equal(end - first, np.asarray(traced[1] - traced[0]))
+    assert (end - first).tolist() == [0 if w is None else w[1] - w[0] for w in want]
+
+
+def test_compute_block_pages_rule():
+    """~256 tokens a compute block, clamped to the table; the VMEM budget
+    cuts it for wide pools (the padded page counts, not the logical one)."""
+    assert compute_block_pages(16, 4, 128, jnp.int8, 192, True) == 16
+    assert compute_block_pages(16, 4, 128, jnp.int8, 5, True) == 5
+    assert compute_block_pages(8, 2, 16, jnp.float32, 4, False) == 4
+    assert compute_block_pages(16, 32, 128, jnp.float32, 192, False) < 16
+    assert compute_block_pages(256, 64, 512, jnp.float32, 192, False) == 1
 
 
 # ---------------------------------------------------------------------------

@@ -16,6 +16,7 @@ import pytest
 from repro.configs import get_arch, reduced
 from repro.configs.base import QuantConfig
 from repro.kernels import ops
+from repro.kernels.paged_attention import compute_block_pages, kv_block_range
 from repro.models.lm import Runtime, init_lm
 from repro.nn.module import unbox
 from repro.obs import (
@@ -308,7 +309,16 @@ def test_traced_engine_spans_and_parity():
 
 def test_traced_megastep_host_loop_spans():
     arch = reduced(get_arch("yi-6b"))
-    e = PagedServeEngine(arch, _params(arch), obs=Obs(trace=True), decode_steps=2, **KW)
+    e = PagedServeEngine(arch, _params(arch), obs=Obs(trace=True), decode_steps=2,
+                         rt=Runtime(decode_kernel=True), **KW)
+    lens_at_megastep = []
+    megastep = e.megastep
+
+    def recorded():
+        lens_at_megastep.append(e.cache.lens.copy())
+        return megastep()
+
+    e.megastep = recorded
     prompts = _prompts(arch, n=3)
     e.generate(prompts, max_new=5)
     tr = e.obs.trace
@@ -325,6 +335,19 @@ def test_traced_megastep_host_loop_spans():
     assert sum(a["admitted"] for *_, a in tr.spans("admission")) == len(prompts)
     assert sum(a["tokens"] for *_, a in tr.spans("replay")) == e.stats["decode_tokens"]
     assert sum(a["released"] for *_, a in tr.spans("replay")) == len(prompts)
+    # the paged decode kernel's walk at each megastep's first tick: every
+    # row at its length with that tick's token, counted by the helper that
+    # bounds the kernel's walk
+    kp = e.cache.pools["0"]["attn"]["kp"]
+    _, _, bs, kv, width = kp.shape
+    mb = e.cache.max_blocks_per_seq
+    pages = compute_block_pages(bs, kv, width, kp.dtype, mb, False)
+    assert len(lens_at_megastep) == len(mega)
+    for lens, (*_, args) in zip(lens_at_megastep, mega):
+        first, end = kv_block_range(lens + 1, pages * bs)
+        assert args["kv_blocks_walked"] == int((end - first).sum())
+        assert args["kv_blocks_grid"] == e.batch * -(-mb // pages)
+        assert 0 < args["kv_blocks_walked"] <= args["kv_blocks_grid"]
 
 
 def test_untraced_engine_records_no_events():

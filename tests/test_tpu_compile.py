@@ -66,9 +66,14 @@ def test_int_matmul_compiles(one_chip, mode, M, K, N):
     _compile(fn, one_chip, *shapes)
 
 
-@pytest.mark.parametrize("kv", ["fp", "int8", "int4"])
-def test_paged_attention_compiles(one_chip, kv):
-    B, KV, G, Dh, bs, NB, MB = 8, 4, 8, 128, 16, 513, 64
+@pytest.mark.parametrize(
+    "kv,B,NB,MB",
+    [("fp", 8, 513, 64), ("int8", 8, 513, 64), ("int4", 8, 513, 64),
+     ("int8", 24, 3600, 192)],  # the yi6b-decode-offline cell's decode call
+    ids=["fp", "int8", "int4", "int8-cell"],
+)
+def test_paged_attention_compiles(one_chip, kv, B, NB, MB):
+    KV, G, Dh, bs = 4, 8, 128, 16
     pool_dt, Dhp = {"fp": (jnp.bfloat16, Dh), "int8": (jnp.int8, Dh),
                     "int4": (jnp.uint8, Dh // 2)}[kv]
     shapes = [((B, KV * G, Dh), jnp.bfloat16), ((NB, bs, KV, Dhp), pool_dt),
