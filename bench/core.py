@@ -113,7 +113,7 @@ def device_info(devices) -> dict:
 
 
 def peaks(device_kind: str) -> dict:
-    """The chip's published peaks; an unknown device is an error."""
+    """One chip's published peaks; an unknown device is an error."""
     table = load_json(BENCH_DIR / "peaks.json")["devices"]
     if device_kind not in table:
         raise KeyError(f"no peaks for device kind {device_kind!r} in bench/peaks.json")
@@ -172,7 +172,9 @@ class RunRecord:
     ``spans``: program spans ``(name, t0_s, dur_s, args)`` on ``perf_counter``;
     ``counters``: the program's and the benchmark's counts; ``trace``: the
     reduced profiler trace (``trace_reduce.Reduced``) or None; ``work``: the
-    operations and bytes of the traced window by kernel (``work.py``)."""
+    operations and bytes of the traced window by kernel (``work.py``);
+    ``peaks``: the rates of the cell's chips taken together (``bf16_flops``,
+    ``int8_ops``, ``hbm_bytes_s``) and one chip's capacity ``hbm_bytes``."""
 
     cell: Cell
     window: tuple = (0.0, 0.0)

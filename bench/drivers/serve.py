@@ -172,17 +172,19 @@ def run(ctx) -> dict:
     t0 = time.perf_counter()
     tracer = _Tracer(ctx, t0)
     served0 = _served(reqs)
-    while True:
-        now = time.perf_counter()
-        tracer.poll(now)
-        if now - t0 >= ctx.seconds and not tracer.waiting():
-            break
-        if eng.sched.idle():
-            raise RuntimeError("the offline backlog ran dry inside the window; the cell needs a "
-                               "longer backlog")
-        _step(eng, tracer, steps, prompt_len)
-    t1 = time.perf_counter()
-    tracer.stop()
+    try:
+        while True:
+            now = time.perf_counter()
+            tracer.poll(now)
+            if now - t0 >= ctx.seconds and not tracer.waiting():
+                break
+            if eng.sched.idle():
+                raise RuntimeError("the offline backlog ran dry inside the window; the cell needs "
+                                   "a longer backlog")
+            _step(eng, tracer, steps, prompt_len)
+        t1 = time.perf_counter()
+    finally:
+        tracer.stop()
     ctx.end_window()
     emitted = _served(reqs) - served0
     e2e = {"output_tok_s": emitted / (t1 - t0)}

@@ -8,6 +8,12 @@ then runs the same object on.  ``train_tok_s`` is the tokens of every step
 completed in the window (each ends in ``block_until_ready``) over the
 window's length.
 
+On more than one device the step, its state and each batch are laid out
+as ``repro.launch.train.train`` lays them out: the planned ``data x model``
+mesh, ``ShardingRules.default``, the state by ``make_state_specs``, a batch
+by the ``batch`` rule; the weights are made straight into their shardings.
+The reference runs on the same mesh.  On one device there is no mesh.
+
 Once the window has closed and the program's state is freed, the plain
 float32 reference (``reference/train.py``) runs the same first steps from
 the same weights and rows.  Compared, each by the worst leaf where a leaf is
@@ -30,7 +36,34 @@ from bench.model import arch_from_config, make_params
 from bench.traffic import tokens as feed
 
 
-def _program_step(arch, cfg):
+def _layout(arch, devices) -> tuple:
+    """The mesh and sharding rules ``repro.launch.train.train`` builds over
+    ``devices``, with the plan; ``(None, None, None)`` on one device."""
+    if len(devices) == 1:
+        return None, None, None
+    from repro.dist.sharding import ShardingRules, make_mesh
+    from repro.train.elastic import plan_mesh
+
+    plan = plan_mesh(len(devices), model_divisors=[s.attn.heads for s in arch.stacks if s.attn])
+    mesh = make_mesh(plan["shape"], plan["axes"], devices=devices)
+    return mesh, ShardingRules.default(mesh, arch), dict(zip(plan["axes"], plan["shape"]))
+
+
+def _shardings(arch, opt, mesh, rules, rows: int, seq: int) -> tuple:
+    """Where the train state and a ``(rows, seq)`` batch live on ``mesh``."""
+    import jax
+    from jax.sharding import NamedSharding
+
+    from repro.dist.sharding import resolve_pspec
+    from repro.models.lm import init_lm
+    from repro.train.state import make_state_specs, specs_to_shardings
+
+    boxed = jax.eval_shape(lambda: init_lm(jax.random.PRNGKey(0), arch))
+    state = specs_to_shardings(make_state_specs(boxed, opt, mesh, rules), mesh)
+    return state, NamedSharding(mesh, resolve_pspec(("batch", None), (rows, seq), mesh, rules))
+
+
+def _program_step(arch, cfg, mesh=None, rules=None):
     import jax
 
     from repro.models.lm import Runtime
@@ -41,7 +74,8 @@ def _program_step(arch, cfg):
     t = cfg["train"]
     opt = adamw(b1=t["b1"], b2=t["b2"], eps=t["eps"], weight_decay=t["weight_decay"])
     sched = cosine_with_warmup(t["lr"], warmup=t["warmup"], total=t["total_steps"])
-    fn = build_train_step(arch, opt, Runtime(), lr_schedule=sched, grad_clip=t["grad_clip"])
+    fn = build_train_step(arch, opt, Runtime(mesh=mesh, rules=rules), lr_schedule=sched,
+                          grad_clip=t["grad_clip"])
     return jax.jit(fn, donate_argnums=(0,)), opt
 
 
@@ -91,13 +125,23 @@ def run(ctx) -> dict:
     cfg, w = ctx.cell.config, ctx.cell.workload
     arch = arch_from_config(cfg)
     rows, seq, n_check = int(w["batch"]), int(w["seq"]), int(w["check_steps"])
+    mesh, rules, ctx.counters["mesh"] = _layout(arch, ctx.devices)
+    step_fn, opt = ctx.overrides.get("train_step") or _program_step(arch, cfg, mesh, rules)
+    state_at, batch_at = _shardings(arch, opt, mesh, rules, rows, seq) if mesh else (None, None)
+    params_at = state_at["params"] if mesh else None
     t = time.perf_counter()
-    params = jax.block_until_ready(make_params(cfg, ctx.seed, deployed=False))
+    params = jax.block_until_ready(make_params(cfg, ctx.seed, deployed=False, shardings=params_at))
     params0 = jax.device_get(params)  # on the host: the step's memory is the program's
     ctx.setup["weights_s"] = time.perf_counter() - t
-    step_fn, opt = ctx.overrides.get("train_step") or _program_step(arch, cfg)
     state = {"params": params, "opt_state": opt.init(params), "step": jnp.zeros((), jnp.int32)}
-    batch = lambda i: feed.batch(ctx.seed, i, rows, seq, cfg["vocab_size"])
+    if mesh:
+        state = jax.device_put(state, state_at)
+
+    def batch(i):
+        b = feed.batch(ctx.seed, i, rows, seq, cfg["vocab_size"])
+        # one device: the step takes the host arrays, its transfer inside the call
+        return jax.device_put(b, batch_at) if mesh else b
+
     b1 = cfg["train"]["b1"]
 
     t = time.perf_counter()
@@ -108,7 +152,7 @@ def run(ctx) -> dict:
         if i == 0:  # AdamW's first moment after one step is (1 - b1) x the gradient it got
             grad_prog = {k: v / (1 - b1) for k, v in _leaf_norms(state["opt_state"]["m"]).items()}
             m_first = jax.device_get(state["opt_state"]["m"])
-    change_prog = _diff_norms(state["params"], params0)
+    change_prog = _diff_norms(state["params"], jax.device_put(params0, params_at))
     ctx.setup["first_steps_s"] = time.perf_counter() - t
     ctx.begin_window()
 
@@ -118,31 +162,33 @@ def run(ctx) -> dict:
     trace_end = trace_at + float(w.get("trace_seconds", 3.0))
     done, i = 0, n_check
     traced = ctx.counters.setdefault("steps", [])
-    while True:
-        now = time.perf_counter()
-        if trace_on and not tw[0] and now >= trace_at:
-            jax.profiler.start_trace(str(trace_dir))
-            tw[0] = time.perf_counter()
-        if tw[0] and not tw[1] and now >= trace_end:
-            tw[1] = time.perf_counter()
-            jax.profiler.stop_trace()
-        if now - t0 >= ctx.seconds and not (tw[0] and not tw[1] and not traced):
-            break  # a traced run's window holds at least one traced step
-        if tw[0] and not tw[1]:
-            s0 = time.perf_counter()
-            with jax.profiler.TraceAnnotation("bench.step"):
+    try:
+        while True:
+            now = time.perf_counter()
+            if trace_on and not tw[0] and now >= trace_at:
+                jax.profiler.start_trace(str(trace_dir))
+                tw[0] = time.perf_counter()
+            if tw[0] and not tw[1] and now >= trace_end:
+                tw[1] = time.perf_counter()
+                jax.profiler.stop_trace()
+            if now - t0 >= ctx.seconds and not (tw[0] and not tw[1] and not traced):
+                break  # a traced run's window holds at least one traced step
+            if tw[0] and not tw[1]:
+                s0 = time.perf_counter()
+                with jax.profiler.TraceAnnotation("bench.step"):
+                    state, m = step_fn(state, batch(i))
+                    jax.block_until_ready(m["loss"])
+                traced.append({"t0": s0, "t1": time.perf_counter()})
+            else:
                 state, m = step_fn(state, batch(i))
                 jax.block_until_ready(m["loss"])
-            traced.append({"t0": s0, "t1": time.perf_counter()})
-        else:
-            state, m = step_fn(state, batch(i))
-            jax.block_until_ready(m["loss"])
-        done += 1
-        i += 1
-    t1 = time.perf_counter()
-    if tw[0] and not tw[1]:
-        tw[1] = time.perf_counter()
-        jax.profiler.stop_trace()
+            done += 1
+            i += 1
+        t1 = time.perf_counter()
+    finally:
+        if tw[0] and not tw[1]:
+            tw[1] = time.perf_counter()
+            jax.profiler.stop_trace()
     ctx.end_window()
     if tw[1]:
         ctx.trace_dir = trace_dir
@@ -159,19 +205,21 @@ def run(ctx) -> dict:
     from bench.reference import train as ref
 
     rpb = int(w["reference_rows_per_block"])
-    rstep = jax.jit(lambda s, b: ref.step(s, b, cfg, rows_per_block=rpb))
-    params0 = jax.device_put(params0)
+    # the state is donated and each step's gradients dropped before the
+    # next, so that no more than one state and one gradient are live
+    rstep = jax.jit(lambda s, b: ref.step(s, b, cfg, rows_per_block=rpb), donate_argnums=(0,))
     with jax.default_matmul_precision("highest"):
-        rs = ref.init_state(params0)
+        rs = ref.init_state(jax.device_put(params0, params_at))
         ref_losses, grad_ref = [], None
         for j in range(n_check):
             rs, l, g = rstep(rs, batch(j))
             ref_losses.append(float(l))
             if j == 0:
                 grad_ref = _leaf_norms(g)
-                grad_diff = _diff_norms(jax.device_put(m_first), g, 1.0 / (1 - b1))
+                grad_diff = _diff_norms(jax.device_put(m_first, params_at), g, 1.0 / (1 - b1))
                 del m_first
-        change_ref = _diff_norms(rs["params"], params0)
+            del g
+        change_ref = _diff_norms(rs["params"], jax.device_put(params0, params_at))
     ctx.counters["reference_s"] = time.perf_counter() - t
     g_gaps = leaf_gaps(grad_prog, grad_ref, grad_ref)
     c_gaps = leaf_gaps(change_prog, change_ref, grad_ref)

@@ -117,11 +117,12 @@ def _layer(key, cfg: dict, deployed: bool) -> dict:
     return out
 
 
-def make_params(cfg: dict, seed: int, *, deployed: bool) -> dict:
+def make_params(cfg: dict, seed: int, *, deployed: bool, shardings=None) -> dict:
     """The model's weights from ``seed``, built on the device in one jitted
     program: int8 codes and scales (``deployed``, the serving artifact) or
     the A2Q training parameters ``(v, t, d)``.  The layer stack is a
-    ``lax.map``, so one layer's float draw is live at a time."""
+    ``lax.map``, so one layer's float draw is live at a time.  ``shardings``
+    (a tree like the weights') places them across a mesh as they are made."""
     d, V, L = cfg["hidden_size"], cfg["vocab_size"], cfg["num_hidden_layers"]
     q = cfg["a2q"]
 
@@ -141,7 +142,7 @@ def make_params(cfg: dict, seed: int, *, deployed: bool) -> dict:
             p["head"]["aq"] = {"log2_scale": _act_scale(q)}
         return p
 
-    return jax.jit(build)(prng_key(seed))
+    return jax.jit(build, out_shardings=shardings)(prng_key(seed))
 
 
 def prng_key(seed: int):

@@ -13,7 +13,9 @@ numbers end standard error.
 
 ``--trace 0`` reports the cell's end-to-end metrics; ``--trace 1`` takes a
 profiler trace of a few seconds of the window and reports the cell's
-per-layer metrics, each read by ``bench/metrics/<name>.py``.
+per-layer metrics, each read by ``bench/metrics/<name>.py``.  A share of a
+peak or a roofline is taken over the cell's chips: the readers get the
+rates of all of them together, and device time averaged over them.
 
 Without a TPU, or with fewer chips than the cell asks for, it exits
 non-zero and prints no result.
@@ -134,7 +136,9 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *, root: pathlib
         ctx.counters["trace_ops"] = sorted(([k[:400], v] for k, v in red.ops.items()),
                                            key=lambda kv: -kv[1])[:40]
         ctx.record.trace = red
-        ctx.record.peaks = core.peaks(devices[0].device_kind)
+        chip = core.peaks(devices[0].device_kind)  # rates add up over chips, capacity does not
+        ctx.record.peaks = dict(chip, **{k: chip[k] * len(devices)
+                                         for k in ("bf16_flops", "int8_ops", "hbm_bytes_s")})
         ctx.record.work = work.window_work(ctx.record)
         device.update(busy_s=red.busy_s, window_s=red.window_s)
         breakdown = {"device_ops": red.top_ops(10), "idle_gaps": red.top_gaps(10)}
