@@ -10,6 +10,13 @@ with the exact same code paths the dry-run compiles.
 
 Elastic restart: rerun the same command after changing the device fleet; the
 mesh planner re-plans and the checkpoint re-shards onto the new mesh.
+
+Before the first step the launcher compiles the step for the placed state
+and logs the census of the cross-chip collectives one step runs
+(``dist.census``), recording it as the counters ``train_collectives`` and
+``train_collective_bytes`` (label ``kind``) of the trainer's
+``obs.metrics``; the trainer's jitted step then runs that compile and asks
+for no other (``tests/test_sharding.py`` counts the compiles).
 """
 
 from __future__ import annotations
@@ -23,12 +30,14 @@ import jax.numpy as jnp
 
 from repro.configs import get_arch, reduced
 from repro.data.synthetic import TokenStream
+from repro.dist.census import collective_census
 from repro.dist.collectives import GradCompressConfig, resolve_grad_compress
 from repro.dist.sharding import ShardingRules, make_mesh, param_specs
 from repro.launch.cache import enable_compile_cache
 from repro.models.lm import Runtime, init_lm
 from repro.models.steps import build_train_step
 from repro.nn.module import unbox
+from repro.obs import Obs
 from repro.optim.optimizers import adamw, adafactor, sgdm
 from repro.optim.schedules import cosine_with_warmup
 from repro.train.checkpoint import install_signal_handler
@@ -53,13 +62,16 @@ def train(
     ckpt_dir: Optional[str] = None,
     ckpt_every: int = 50,
     seed: int = 0,
+    obs: Optional[Obs] = None,
 ):
     """Train ``arch`` for ``steps`` steps on ``devices`` (default: all).
 
     More than one device (and ``use_mesh``) builds the planned data x model
     mesh over them and places the train state by ``make_state_specs``;
-    otherwise the state lives on the first device.  Returns the
-    ``TrainLoopResult`` (one history record per step)."""
+    otherwise the state lives on the first device.  ``obs`` receives the
+    collective census and the ``train_step`` spans (default: a fresh
+    ``Obs()``, tracing off).  Returns the ``TrainLoopResult`` (one history
+    record per step)."""
     devices = list(devices) if devices is not None else jax.devices()
     mesh = None
     rules = None
@@ -94,6 +106,7 @@ def train(
         ckpt_every=ckpt_every,
         log_every=1,
         watchdog=StragglerWatchdog(),
+        obs=obs,
     )
     # older checkpoints have no grad_err leaves; residuals restart from zeros
     state, start = trainer.maybe_restore(state, allow_missing=gc is not None)
@@ -102,9 +115,24 @@ def train(
     if mesh is not None:
         specs = make_state_specs(boxed, opt, mesh, rules, grad_compress=gc)
         state = jax.device_put(state, specs_to_shardings(specs, mesh))
+    record_census(trainer, state, start)
     if ckpt_dir:
         install_signal_handler(trainer.emergency_save)
     return trainer.run(state, steps, start_step=start)
+
+
+def record_census(trainer: Trainer, state, step: int) -> dict:
+    """Compile ``trainer``'s step for ``state`` and the batch of ``step``,
+    log the census of its collectives and add it to the trainer's counters
+    (once per compiled step)."""
+    batch = trainer.shard_batch(trainer.batch_fn(step))
+    census = collective_census(trainer.step_fn.lower(state, batch).compile().as_text())
+    for kind, c in census.items():
+        trainer.obs.metrics.counter("train_collectives", {"kind": kind}).inc(c["count"])
+        trainer.obs.metrics.counter("train_collective_bytes", {"kind": kind}).inc(c["bytes"])
+    print("collectives a step: " + (", ".join(
+        f"{kind} {c['count']} ({c['bytes']} B)" for kind, c in census.items() if c["count"]) or "none"))
+    return census
 
 
 def main(argv=None):

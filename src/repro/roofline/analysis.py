@@ -7,12 +7,14 @@
 Sources: ``compiled.cost_analysis()`` provides per-device FLOPs and bytes
 (XLA's post-partitioning module is the per-device program, so these are
 already divided by the mesh).  Collective bytes are NOT in cost_analysis:
-``collective_bytes_from_hlo`` parses the optimized HLO and sums the result
-sizes of every all-gather / all-reduce / reduce-scatter / all-to-all /
-collective-permute (a consistent per-chip wire-bytes proxy: ring all-reduce
-moves ~2x the buffer, all-gather ~1x the result — we record raw result bytes
-per op kind so any convention can be recomputed; the *deltas* the perf loop
-optimizes are convention-independent).
+``collective_bytes_from_hlo`` reads the optimized HLO through
+``dist.census`` (one parser for the launcher's census and the dry-run) and
+sums the result sizes of every all-gather / all-reduce / reduce-scatter /
+all-to-all / collective-permute, a loop body once per trip (a consistent
+per-chip wire-bytes proxy: ring all-reduce moves ~2x the buffer, all-gather
+~1x the result — we record raw result bytes per op kind so any convention
+can be recomputed; the *deltas* the perf loop optimizes are
+convention-independent).
 
 ``MODEL_FLOPS = 6*N*D`` (dense) / ``6*N_active*D`` (MoE) gives the useful-work
 ratio that catches remat/redundancy waste.
@@ -30,80 +32,34 @@ the basis for the ``wire_bytes_saved`` number the dry-run records.
 
 from __future__ import annotations
 
-import dataclasses
-import re
-from typing import Optional
-
+from repro.dist.census import KINDS, collective_ops
 from repro.roofline import hw
 
 __all__ = ["collective_bytes_from_hlo", "wire_bytes", "roofline_terms", "model_flops"]
-
-_DTYPE_BYTES = {
-    "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
-    "s64": 8, "u64": 8, "s32": 4, "u32": 4, "s16": 2, "u16": 2, "s8": 1,
-    "u8": 1, "pred": 1, "c64": 8, "c128": 16, "s4": 1, "u4": 1,
-}
-
-_COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all", "collective-permute")
-
-# result of an HLO op:  %name = bf16[8,128,4096]{2,1,0} all-reduce(...)
-_OP_RE = re.compile(
-    r"=\s*(?:\()?\s*([a-z0-9]+)\[([0-9,]*)\][^=]*?\b(" + "|".join(_COLLECTIVES) + r")\b"
-)
-_TUPLE_RE = re.compile(r"([a-z0-9]+)\[([0-9,]*)\]")
-
-
-def _shape_bytes(dtype: str, dims: str) -> int:
-    n = 1
-    for d in dims.split(","):
-        if d:
-            n *= int(d)
-    return n * _DTYPE_BYTES.get(dtype, 4)
-
 
 _GRADIENT_WIRE_DTYPES = ("s8", "u8", "s16", "u16")
 
 
 def collective_bytes_from_hlo(hlo_text: str) -> dict:
-    """Sum collective result bytes by op kind over an optimized HLO module.
+    """Sum collective result bytes by op kind over an optimized HLO module,
+    as ``dist.census`` counts them (a ``while`` body once per trip, an async
+    pair once, at its ``-done``).
 
     Low-bit integer (s8/s16) all-gather / all-to-all results are additionally
     classified as compressed-gradient traffic (``gradient_wire_bytes``): only
     ``dist.collectives`` puts integer payloads that narrow on the wire.
     """
-    per_kind = {k: 0 for k in _COLLECTIVES}
-    counts = {k: 0 for k in _COLLECTIVES}
+    per_kind = {k: 0 for k in KINDS}
+    counts = {k: 0 for k in KINDS}
     gradient_wire = 0
     gradient_count = 0
-    for line in hlo_text.splitlines():
-        stripped = line.strip()
-        kind = None
-        for k in _COLLECTIVES:
-            # match the op name with word-ish boundaries: "all-reduce(", "all-reduce-start("
-            if f" {k}(" in stripped or f" {k}-start(" in stripped or f"{k}-done(" in stripped:
-                kind = k
-                break
-        if kind is None:
-            continue
-        if f"{kind}-done(" in stripped:
-            continue  # avoid double counting start/done pairs
-        # take the result type(s) on the lhs of '='
-        lhs = stripped.split("=", 1)
-        if len(lhs) != 2:
-            continue
-        header = lhs[1].split(kind)[0]
-        total = 0
-        int_bytes = 0
-        for dtype, dims in _TUPLE_RE.findall(header):
-            nbytes = _shape_bytes(dtype, dims)
-            total += nbytes
-            if dtype in _GRADIENT_WIRE_DTYPES:
-                int_bytes += nbytes
-        per_kind[kind] += total
-        counts[kind] += 1
+    for kind, arrays, times in collective_ops(hlo_text):
+        per_kind[kind] += times * sum(n for _, n in arrays)
+        counts[kind] += times
+        int_bytes = sum(n for dtype, n in arrays if dtype in _GRADIENT_WIRE_DTYPES)
         if int_bytes and kind in ("all-gather", "all-to-all"):
-            gradient_wire += int_bytes
-            gradient_count += 1
+            gradient_wire += times * int_bytes
+            gradient_count += times
     return {
         "bytes_by_kind": per_kind,
         "counts": counts,

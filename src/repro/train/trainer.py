@@ -1,6 +1,12 @@
 """Training loop: jitted step, metrics, checkpointing, watchdog, emergency
 save.  Works identically on 1 CPU device (examples/tests) and on a production
-mesh (launch/train.py passes shardings + Runtime)."""
+mesh (launch/train.py passes shardings + Runtime).
+
+Each step runs inside a ``train_step`` span (args ``step``) of the trainer's
+``obs.Tracer``: from the call to the loss being ready, on ``perf_counter``
+and, when the tracer is enabled, as a profiler annotation.  The default
+tracer is disabled and costs nothing; the span is host-side and changes
+nothing in the compiled step."""
 
 from __future__ import annotations
 
@@ -12,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import Obs
 from repro.train import checkpoint as ckpt
 from repro.train.elastic import StragglerWatchdog
 
@@ -27,7 +34,9 @@ class TrainLoopResult:
 
 class Trainer:
     """Drives ``step_fn(state, batch) -> (state, metrics)`` over a stateless
-    batch source (``batch_fn(step) -> dict``)."""
+    batch source (``batch_fn(step) -> dict``).  ``obs`` carries the tracer
+    the ``train_step`` spans go to and the metrics registry the launcher
+    records into (default: ``Obs()``, tracing off)."""
 
     def __init__(
         self,
@@ -41,6 +50,7 @@ class Trainer:
         donate: bool = True,
         watchdog: Optional[StragglerWatchdog] = None,
         shard_batch: Optional[Callable[[dict], Any]] = None,
+        obs: Optional[Obs] = None,
     ):
         self.batch_fn = batch_fn
         self.ckpt_dir = ckpt_dir
@@ -48,6 +58,7 @@ class Trainer:
         self.keep = keep
         self.log_every = log_every
         self.watchdog = watchdog or StragglerWatchdog()
+        self.obs = obs or Obs()
         self.shard_batch = shard_batch or (lambda b: {k: jnp.asarray(v) for k, v in b.items()})
         self.step_fn = jax.jit(step_fn, donate_argnums=(0,) if donate else ())
         self._last_state = None
@@ -78,8 +89,9 @@ class Trainer:
         for i in range(start, start + n_steps):
             batch = self.shard_batch(self.batch_fn(i))
             t0 = time.perf_counter()
-            state, metrics = self.step_fn(state, batch)
-            jax.block_until_ready(metrics["loss"])
+            with self.obs.trace.span("train_step", {"step": i}):
+                state, metrics = self.step_fn(state, batch)
+                jax.block_until_ready(metrics["loss"])
             dt = time.perf_counter() - t0
             self._last_state = state
             self.watchdog.observe(i, dt)

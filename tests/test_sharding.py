@@ -71,10 +71,10 @@ def test_batch_axes_multi_pod():
     assert spec == P(("pod", "data"), None, None)
 
 
-def _run_subprocess(body: str, n_dev: int = 8) -> str:
+def _run_subprocess(body: str, n_dev: int = 8, env: dict = None) -> str:
     # every script builds its meshes with the Auto-axis helper
     code = "from repro.dist.sharding import make_mesh\n" + textwrap.dedent(body)
-    env = dict(os.environ)
+    env = dict(os.environ, **(env or {}))
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_dev}"
     env["PYTHONPATH"] = SRC
     out = subprocess.run(
@@ -432,3 +432,162 @@ def test_elastic_reshard_restore():
         """
     )
     assert "OK" in out
+
+
+# -- collective census of the launcher's compiled step (dist/census.py) ---------
+
+# A tiny yi-6b twin on the launcher's path: 16 heads over 4 KV heads, d 128,
+# d_ff 344, trained 2 steps of 4 x 32 tokens at 2 layers on four devices
+# (mesh data 1 x model 4) with tracing on and off, at 3 layers on four and at
+# 2 on one.  The census the launcher records is read back from its counters;
+# the HLO text it was taken from is kept as well, and the step's compiles are
+# counted from JAX's compile events.  JAX's compile cache (a temporary
+# directory) gives the untraced run the traced run's program; a cache hit
+# still sends its compile event, so the count sees every compile asked for.
+_CENSUS_RUNS = """
+import dataclasses, json, jax
+from jax import monitoring
+from repro.configs import get_arch, reduced
+from repro.launch import train as launch
+from repro.obs import Obs
+
+compiles = []
+monitoring.register_event_duration_secs_listener(
+    lambda event, secs, **kw: compiles.append(kw.get("fun_name"))
+    if event == "/jax/core/compile/backend_compile_duration" else None)
+texts = []
+count = launch.collective_census
+launch.collective_census = lambda text: (texts.append(text), count(text))[1]
+base = reduced(get_arch("yi-6b"), head_dim=8)
+def arch(layers):
+    s = base.stacks[0]
+    attn = dataclasses.replace(s.attn, heads=16, kv_heads=4)
+    return dataclasses.replace(base, d_model=128, stacks=(dataclasses.replace(
+        s, attn=attn, d_ff=344, count=layers),))
+out = {}
+for name, layers, n_dev, trace in [("L2-traced", 2, 4, True), ("L2", 2, 4, False),
+                                   ("L3", 3, 4, False), ("one", 2, 1, False)]:
+    obs = Obs(trace=trace)
+    compiles.clear()
+    launch.train(arch(layers), steps=2, batch=4, seq=32, devices=jax.devices()[:n_dev], obs=obs)
+    out[name] = {"counters": obs.metrics.snapshot(), "text": texts[-1],
+                 "spans": [[n, a] for n, _, _, a in obs.trace.spans()],
+                 "step_compiles": compiles.count("jit(train_step)")}
+print(json.dumps(out))
+"""
+_B, _T, _D = 4, 32, 128
+
+
+@pytest.fixture(scope="module")
+def census_runs(tmp_path_factory):
+    cache = {"JAX_COMPILATION_CACHE_DIR": str(tmp_path_factory.mktemp("census_cache")),
+             "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
+             "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES": "-1"}
+    return json.loads(_run_subprocess(_CENSUS_RUNS, n_dev=4, env=cache).strip().splitlines()[-1])
+
+
+def _census(run: dict) -> dict:
+    out = {}
+    for key, v in run["counters"].items():
+        name, kind = key.rstrip("}").split("{kind=")
+        out.setdefault(kind, {})["count" if name == "train_collectives" else "bytes"] = v["value"]
+    return out
+
+
+def test_census_tp4_step_all_reduces_per_layer(census_runs):
+    """Tensor parallel 4 puts all-reduces, and only all-reduces, into the
+    step, and each layer adds the same bytes: 8 all-reduces of one
+    float32 (B, T, d) activation (the row-parallel o and down projections
+    forward, o again in the block's recompute, the input gradients of the
+    column-parallel q, k, v, gate and in), plus per-channel l1 norms of
+    the row-parallel weights and scalar partial sums, under one
+    activation's worth.  Outside the layers the step adds the head's input
+    gradient and the vocabulary-split loss's reductions: under four
+    activations."""
+    c2, c3 = (_census(census_runs[n]) for n in ("L2", "L3"))
+    assert set(c2) == {"all-reduce", "all-gather", "reduce-scatter", "collective-permute", "all-to-all"}
+    assert all(c2[k] == {"count": 0, "bytes": 0} for k in c2 if k != "all-reduce")
+    per_layer = c3["all-reduce"]["bytes"] - c2["all-reduce"]["bytes"]
+    act = _B * _T * _D * 4
+    assert per_layer // act == 8 and per_layer % act < act // 8, per_layer
+    assert 0 < c2["all-reduce"]["bytes"] - 2 * per_layer < 4 * act
+
+
+def test_census_one_device_step_is_empty(census_runs):
+    c = _census(census_runs["one"])
+    assert c and all(v == {"count": 0, "bytes": 0} for v in c.values())
+
+
+def test_census_counts_each_compiled_step_once(census_runs):
+    """The counters hold one compiled step's census, not one per step run."""
+    from repro.dist.census import collective_census
+
+    run = census_runs["L2"]
+    assert _census(run) == {k: {"count": float(v["count"]), "bytes": float(v["bytes"])}
+                            for k, v in collective_census(run["text"]).items()}
+
+
+@pytest.mark.parametrize("run", ["L2-traced", "L2", "L3", "one"])
+def test_launcher_compiles_the_step_once(census_runs, run):
+    """The census's compile before the loop is the one the loop runs: the
+    trainer's jitted step asks for no second compile."""
+    assert census_runs[run]["step_compiles"] == 1
+
+
+def test_train_step_span_only_when_tracing(census_runs):
+    assert census_runs["L2"]["spans"] == []
+    assert census_runs["L2-traced"]["spans"] == [["train_step", {"step": 0}], ["train_step", {"step": 1}]]
+
+
+def test_tracing_leaves_the_compiled_step_unchanged(census_runs):
+    assert census_runs["L2-traced"]["text"] == census_runs["L2"]["text"]
+    assert _census(census_runs["L2-traced"]) == _census(census_runs["L2"])
+
+
+_SYNTHETIC_HLO = """HloModule step
+
+%add (x: f32[], y: f32[]) -> f32[] {
+  ROOT %s = f32[] add(f32[] %x, f32[] %y)
+}
+
+%cond (p: (s32[], bf16[8,128])) -> pred[] {
+  %p = (s32[], bf16[8,128]) parameter(0)
+  %constant.3 = s32[]{:T(128)} constant(3)
+  %i = s32[] get-tuple-element(%p), index=0
+  ROOT %lt = pred[] compare(%i, %constant.3), direction=LT
+}
+
+%body (p: (s32[], bf16[8,128])) -> (s32[], bf16[8,128]) {
+  %p = (s32[], bf16[8,128]) parameter(0)
+  %x = bf16[8,128] get-tuple-element(%p), index=1
+  %ar = bf16[8,128]{1,0:T(8,128)} all-reduce(%x), replica_groups=[1,4]<=[4], to_apply=%add
+  %ags = (bf16[8,32], bf16[8,128]) all-gather-start(%x), dimensions={1}
+  %agd = bf16[8,128] all-gather-done(%ags)
+  ROOT %t = (s32[], bf16[8,128]) tuple(%i, %ar)
+}
+
+ENTRY %main (a: bf16[8,128], b: f32[16]) -> bf16[8,128] {
+  %a = bf16[8,128] parameter(0)
+  %b = f32[16] parameter(1)
+  %w = (s32[], bf16[8,128]) while(%t0), condition=%cond, body=%body
+  %ar2 = (f32[16], f32[]) all-reduce(%b, %c), to_apply=%add
+  %cps = (f32[16], f32[16], u32[], u32[]) collective-permute-start(%b), source_target_pairs={{0,1}}
+  %cpd = f32[16] collective-permute-done(%cps)
+  ROOT %r = bf16[8,128] get-tuple-element(%w), index=1
+}
+"""
+
+
+def test_census_counts_loop_trips_and_async_pairs_once():
+    """A ``while`` body counts once per trip (its condition's ``i < 3``),
+    an async pair once at its ``-done``, a tuple result by all its
+    elements; a known trip count, where the text gives one, wins."""
+    from repro.dist.census import collective_census
+
+    c = collective_census(_SYNTHETIC_HLO)
+    assert c["all-reduce"] == {"count": 3 + 1, "bytes": 3 * 8 * 128 * 2 + 16 * 4 + 4}
+    assert c["all-gather"] == {"count": 3, "bytes": 3 * 8 * 128 * 2}
+    assert c["collective-permute"] == {"count": 1, "bytes": 16 * 4}
+    assert c["reduce-scatter"] == c["all-to-all"] == {"count": 0, "bytes": 0}
+    known = _SYNTHETIC_HLO.replace("body=%body", 'body=%body, backend_config={"known_trip_count":{"n":"5"}}')
+    assert collective_census(known)["all-reduce"]["count"] == 5 + 1
