@@ -117,18 +117,6 @@ def run() -> dict:
     print(f"a2q_quantize_2048x512,{us:.1f},vmem={vm}B fits={vm < hw.VMEM_BYTES} exact={ok}")
     rows.append(dict(name="a2q_quantize", vmem=vm, ok=ok))
 
-    # flash attention working set
-    q = jnp.asarray(rng.normal(size=(2, 4, 256, 64)), jnp.float32)
-    us = time_call(lambda: ops.flash_attention(q, q, q, block_q=64, block_k=64))
-    ok = bool(np.allclose(
-        np.asarray(ops.flash_attention(q, q, q, block_q=64, block_k=64)),
-        np.asarray(ref.ref_flash_attention(q, q, q)), atol=2e-5,
-    ))
-    vm = _vmem_claim(((64, 64), jnp.float32), ((64, 64), jnp.float32), ((64, 64), jnp.float32),
-                     ((64, 1), jnp.float32), ((64, 1), jnp.float32), ((64, 64), jnp.float32))
-    print(f"flash_attention_256,{us:.1f},vmem={vm}B (vs dense scores {256*256*4}B/row-block) ok={ok}")
-    rows.append(dict(name="flash", vmem=vm, ok=ok))
-
     # paged attention: fp32 blocks and int8 blocks with in-kernel dequant
     B, KV, G, Dh, NB, bs, MB = 4, 2, 4, 64, 32, 8, 6
     H = KV * G

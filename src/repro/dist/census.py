@@ -23,7 +23,12 @@ body of one entry computation run once.  ``collective_ops`` yields each
 collective with its result arrays and its runs, for callers that split
 the bytes further (``roofline.analysis`` prices integer gradient traffic
 on its own).  A program on one device has no collectives, so every count
-is 0.  Nothing here runs or compiles anything.
+is 0.
+
+``attention_kernel_census`` counts, by the same walk, the training
+attention kernel's custom calls one run executes, by pass (forward, dq,
+dkv; ``kernels.causal_attention.KERNEL_PASSES``): the compiler names each
+such instruction after its kernel.  Nothing here runs or compiles anything.
 """
 
 from __future__ import annotations
@@ -31,7 +36,9 @@ from __future__ import annotations
 import re
 from typing import Iterator
 
-__all__ = ["KINDS", "collective_census", "collective_ops"]
+from repro.kernels.causal_attention import KERNEL_PASSES
+
+__all__ = ["KINDS", "attention_kernel_census", "collective_census", "collective_ops"]
 
 KINDS = ("all-reduce", "all-gather", "reduce-scatter", "collective-permute", "all-to-all")
 
@@ -49,6 +56,7 @@ _TRIPS = re.compile(r'"known_trip_count":\{"n":"(\d+)"\}')
 _BODY = re.compile(r"\bbody=%?([\w.\-]+)")
 _CONDITION = re.compile(r"condition=%?([\w.\-]+)")
 _LESS_THAN = re.compile(r"ROOT .*compare\(%?[\w.\-]+, %?([\w.\-]+)\), direction=LT")
+_KERNEL = re.compile(r"%(" + "|".join(KERNEL_PASSES) + r")\w*(?:\.\d+)? = .*custom_call_target=\"tpu_custom_call\"")
 
 
 def _arrays(result: str) -> list[tuple[str, int]]:
@@ -99,17 +107,14 @@ def _trips(line: str, comps: dict) -> int:
     return next((int(m.group(1)) for m in map(const.search, cond) if m), 1)
 
 
-def collective_ops(hlo_text: str) -> Iterator[tuple[str, list[tuple[str, int]], int]]:
-    """``(kind, [(dtype, bytes) of each result array], runs)`` for each
-    collective instruction of the module, ``runs`` being how many times one
-    run of the module executes it."""
+def _runs(hlo_text: str) -> Iterator[tuple[str, int]]:
+    """Each instruction line of the module with how many times one run of
+    the module executes it."""
     comps, entry = _computations(hlo_text)
 
     def walk(name: str, times: int):
         for line in comps.get(name, ()):
-            op = _OP.search(line)
-            if op and op.group(3) != "-start":
-                yield op.group(2), _arrays(op.group(1)), times
+            yield line, times
             body = _BODY.search(line)
             for callee in _CALLEE.findall(line):
                 for c in callee.split(","):
@@ -117,6 +122,28 @@ def collective_ops(hlo_text: str) -> Iterator[tuple[str, list[tuple[str, int]], 
                     yield from walk(c, times * (_trips(line, comps) if body and body.group(1) == c else 1))
 
     yield from walk(entry, 1)
+
+
+def collective_ops(hlo_text: str) -> Iterator[tuple[str, list[tuple[str, int]], int]]:
+    """``(kind, [(dtype, bytes) of each result array], runs)`` for each
+    collective instruction of the module, ``runs`` being how many times one
+    run of the module executes it."""
+    for line, times in _runs(hlo_text):
+        op = _OP.search(line)
+        if op and op.group(3) != "-start":
+            yield op.group(2), _arrays(op.group(1)), times
+
+
+def attention_kernel_census(hlo_text: str) -> dict:
+    """``{pass: n}`` for each pass of the training attention kernel
+    (``fwd``, ``dq``, ``dkv``): its custom calls one run of the module
+    executes.  0 for each where the module holds no such kernel."""
+    out = dict.fromkeys(KERNEL_PASSES.values(), 0)
+    for line, times in _runs(hlo_text):
+        m = _KERNEL.search(line)
+        if m:
+            out[KERNEL_PASSES[m.group(1)]] += times
+    return out
 
 
 def collective_census(hlo_text: str) -> dict:

@@ -3,7 +3,7 @@ wrappers (ops.py) and pure-jnp oracles (ref.py).  Layers import from ops."""
 
 from repro.kernels.ops import (  # noqa: F401
     a2q_quantize,
-    flash_attention,
+    causal_attention,
     int_matmul,
     paged_attention,
     rwkv6_scan,

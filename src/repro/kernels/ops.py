@@ -17,7 +17,7 @@ import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.a2q_quantize import a2q_quantize_pallas
-from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.causal_attention import causal_attention_pallas
 from repro.kernels.int_matmul import int_matmul_pallas
 from repro.kernels.paged_attention import (
     paged_attention_pallas,
@@ -28,7 +28,7 @@ from repro.kernels.rwkv6_scan import rwkv6_scan_pallas
 __all__ = [
     "int_matmul",
     "a2q_quantize",
-    "flash_attention",
+    "causal_attention",
     "paged_attention",
     "paged_mla_attention",
     "rwkv6_scan",
@@ -214,44 +214,19 @@ def a2q_quantize(
     return deq[:K, :C], q[:K, :C]
 
 
-def flash_attention(
+def causal_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
     v: jnp.ndarray,
     *,
-    causal: bool = True,
-    window: Optional[int] = None,
-    scale: Optional[float] = None,
-    block_q: int = 128,
-    block_k: int = 128,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
-    """Blocked attention over ``(B, H, T, D)`` tensors (KV heads already
-    repeated to H by the GQA layer).  Pads T axes to block multiples."""
-    B, H, Tq, D = q.shape
-    Tk = k.shape[2]
-    bq = min(block_q, _round_up(Tq, 8))
-    bk = min(block_k, _round_up(Tk, 8))
-    qf = q.reshape(B * H, Tq, D)
-    kf = k.reshape(B * H, Tk, D)
-    vf = v.reshape(B * H, Tk, D)
-    qf = _pad_axis(qf, 1, _round_up(Tq, bq))
-    kf = _pad_axis(kf, 1, _round_up(Tk, bk))
-    vf = _pad_axis(vf, 1, _round_up(Tk, bk))
-    out = flash_attention_pallas(
-        qf,
-        kf,
-        vf,
-        causal=causal,
-        window=window,
-        scale=scale,
-        true_q=Tq,
-        true_k=Tk,
-        block_q=bq,
-        block_k=bk,
-        interpret=_default_interpret(interpret),
-    )
-    return out[:, :Tq].reshape(B, H, Tq, D)
+    """Causal grouped-query attention over ``(B, T, H, Dh)`` queries and
+    ``(B, T, KV, Dh)`` keys/values, positions ``0 .. T-1`` on every row, with
+    a backward (``kernels/causal_attention.py``).  ``T`` must be a multiple
+    of ``causal_attention.block_size(T)``; oracle: the attention layer's
+    ``_sdpa``."""
+    return causal_attention_pallas(q, k, v, interpret=_default_interpret(interpret))
 
 
 def paged_attention(

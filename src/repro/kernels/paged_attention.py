@@ -24,8 +24,8 @@ left in HBM, because Mosaic refuses a DMA slice of the fp32 scale pools
 (the page's ``KV`` lanes pad to 128); on a v5e the two cost about the same
 a page, and what made a step cheap was its one-read index maps.
 
-Softmax is the same fp32 online (running max / sum / accumulator) scheme as
-``flash_attention.py``.  A page keeps all of its KV heads (the ``(KV, Dh)``
+Softmax is the fp32 online (running max / sum / accumulator) scheme of
+FlashAttention.  A page keeps all of its KV heads (the ``(KV, Dh)``
 trailing dims stay whole, as the TPU block-tiling rule requires) and the
 kernel loops over the heads; GQA puts the ``G = H // KV`` query group in
 the row dim of each head's score panel.  Key validity comes from the per-row

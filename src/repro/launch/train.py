@@ -15,8 +15,11 @@ Before the first step the launcher compiles the step for the placed state
 and logs the census of the cross-chip collectives one step runs
 (``dist.census``), recording it as the counters ``train_collectives`` and
 ``train_collective_bytes`` (label ``kind``) of the trainer's
-``obs.metrics``; the trainer's jitted step then runs that compile and asks
-for no other (``tests/test_sharding.py`` counts the compiles).
+``obs.metrics``, and beside them how many times one step runs each pass of
+the training attention kernel as ``train_attention_kernels`` (label
+``pass``: ``fwd``, ``dq``, ``dkv``; all 0 off the TPU); the trainer's jitted
+step then runs that compile and asks for no other
+(``tests/test_sharding.py`` counts the compiles).
 """
 
 from __future__ import annotations
@@ -30,7 +33,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_arch, reduced
 from repro.data.synthetic import TokenStream
-from repro.dist.census import collective_census
+from repro.dist.census import attention_kernel_census, collective_census
 from repro.dist.collectives import GradCompressConfig, resolve_grad_compress
 from repro.dist.sharding import ShardingRules, make_mesh, param_specs
 from repro.launch.cache import enable_compile_cache
@@ -123,15 +126,22 @@ def train(
 
 def record_census(trainer: Trainer, state, step: int) -> dict:
     """Compile ``trainer``'s step for ``state`` and the batch of ``step``,
-    log the census of its collectives and add it to the trainer's counters
-    (once per compiled step)."""
+    log the census of its collectives and of its attention kernel's passes
+    and add both to the trainer's counters (once per compiled step).
+    Returns the collectives' census."""
     batch = trainer.shard_batch(trainer.batch_fn(step))
-    census = collective_census(trainer.step_fn.lower(state, batch).compile().as_text())
+    text = trainer.step_fn.lower(state, batch).compile().as_text()
+    census = collective_census(text)
+    kernels = attention_kernel_census(text)
+    metrics = trainer.obs.metrics
     for kind, c in census.items():
-        trainer.obs.metrics.counter("train_collectives", {"kind": kind}).inc(c["count"])
-        trainer.obs.metrics.counter("train_collective_bytes", {"kind": kind}).inc(c["bytes"])
+        metrics.counter("train_collectives", {"kind": kind}).inc(c["count"])
+        metrics.counter("train_collective_bytes", {"kind": kind}).inc(c["bytes"])
+    for p, n in kernels.items():
+        metrics.counter("train_attention_kernels", {"pass": p}).inc(n)
     print("collectives a step: " + (", ".join(
-        f"{kind} {c['count']} ({c['bytes']} B)" for kind, c in census.items() if c["count"]) or "none"))
+        f"{kind} {c['count']} ({c['bytes']} B)" for kind, c in census.items() if c["count"]) or "none")
+        + "; attention kernels a step: " + ", ".join(f"{p} {n}" for p, n in kernels.items()))
     return census
 
 

@@ -214,7 +214,7 @@ def apply_lm(
             sc = cache.get(str(i)) if cache is not None else None
             x, nc, pen = apply_stack(
                 sp, x, arch, s, positions, sc,
-                mesh=rt.mesh, ep_axis=rt.ep_axis, mla_absorb=rt.mla_absorb,
+                mesh=rt.mesh, rules=rt.rules, ep_axis=rt.ep_axis, mla_absorb=rt.mla_absorb,
                 view=view, decode_kernel=rt.decode_kernel,
                 int_forward=rt.int_forward, int_chain=rt.int_chain,
             )
@@ -281,6 +281,7 @@ def lm_loss(params, arch: ArchConfig, batch: dict, rt: Optional[Runtime] = None)
         pos = jnp.broadcast_to(jnp.arange(Sm, dtype=jnp.int32)[None], (Bm, Sm))
         hm, _, mtp_pen = apply_stack(
             mtp["block"], hm, arch, _mtp_stackcfg(arch), pos, None, mesh=rt.mesh,
+            rules=rt.rules,
         )
         mtp_logits = _head_logits(params, arch, hm, rt)
         mtp_loss, _ = _cross_entropy(mtp_logits, targets[:, 1:])

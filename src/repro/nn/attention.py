@@ -6,8 +6,12 @@ MLA down/up projections) exactly as to any other matmul (DESIGN.md Sec. 5).
 The softmax path is the memory-bounded *query-chunked* jnp implementation —
 ``lax.map`` over query blocks keeps the live score buffer at
 ``(B, tc, H, S)`` — which is both the CPU/dry-run execution path and the
-oracle for the Pallas flash kernel (``kernels/flash_attention.py``, the TPU
-fast path).
+oracle for the training kernel.  On a TPU, a cache-less causal GQA call
+(training, and any forward over a whole sequence) whose length is a
+multiple of the kernel's block runs the blocked causal kernel with a
+backward instead (``kernels/causal_attention.py``); under a mesh it runs
+inside a ``shard_map`` over each chip's own heads (``_causal_kernel``).
+Windowed, chunked and MLA layers and every cached call keep ``_sdpa``.
 
 KV caches (the *cache view* interface — all layouts share one ``_sdpa``):
 * full      — ``(B, S_max, KV, Dh)``, decode writes at ``pos``;
@@ -48,6 +52,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import AttnConfig, QuantConfig
+from repro.dist.sharding import ShardingRules, resolve_pspec
 from repro.nn.embedding import apply_rope
 from repro.nn.linear import apply_linear, init_linear, linear_penalty
 from repro.nn.norms import apply_norm, init_norm
@@ -124,6 +129,40 @@ def _sdpa(
         if rem:
             out = jnp.concatenate([out, block(qg[:, Tm:], qpos[:, Tm:])], axis=1)
     return out.reshape(B, T, H, Dv).astype(q.dtype)
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _causal_kernel(qh, kh, vh, a: AttnConfig, mesh, rules: Optional[ShardingRules]):
+    """Cache-less attention through the causal kernel, or None where
+    ``_sdpa`` keeps the call: off the TPU, for windowed or chunked layers,
+    for a length no kernel block divides, and under a mesh whose rules do
+    not split query and KV heads alike.
+
+    A Pallas call cannot be partitioned by GSPMD, so under ``mesh`` and its
+    ``rules`` the kernel runs in a ``shard_map`` whose specs are the
+    activations' own (``batch`` and ``heads``/``kv_heads``): query heads
+    are laid out ``KV x G``, so a chip's query heads read exactly its KV
+    heads and no collective is needed."""
+    from repro.kernels import ops
+    from repro.kernels.causal_attention import block_size
+
+    T = qh.shape[1]
+    if not (_on_tpu() and a.causal and a.window is None and a.chunk is None
+            and block_size(T) is not None):
+        return None
+    if mesh is None or rules is None:
+        return ops.causal_attention(qh, kh, vh)
+    q_spec = resolve_pspec(("batch", None, "heads", None), qh.shape, mesh, rules)
+    kv_spec = resolve_pspec(("batch", None, "kv_heads", None), kh.shape, mesh, rules)
+    if q_spec != kv_spec:
+        return None
+    return jax.shard_map(
+        ops.causal_attention, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
+        out_specs=q_spec, check_vma=False,
+    )(qh, kh, vh)
 
 
 # ---------------------------------------------------------------------------
@@ -343,6 +382,8 @@ def apply_attention(
     decode_kernel: bool = False,
     int_forward: bool = False,
     int_chain: bool = False,
+    mesh=None,
+    rules: Optional[ShardingRules] = None,
 ) -> tuple[jnp.ndarray, Optional[dict]]:
     """Returns (output, updated cache).  ``cache`` given => cached step over
     ``T >= 1`` new tokens (decode or chunked prefill).  A paged cache (keys
@@ -350,6 +391,9 @@ def apply_attention(
     ``view``; ``decode_kernel=True`` routes the paged ``T == 1`` read through
     the Pallas paged-attention kernel instead of the gathered-view ``_sdpa``.
     ``int_forward`` routes deployed projections through the fused W8A8 path.
+    No cache => ``positions`` are ``0 .. T-1`` on every row, and on a TPU a
+    causal GQA layer runs the causal kernel (``_causal_kernel``, per chip's
+    heads under ``mesh`` by ``rules``).
 
     Every attention projection is a chain break — wq/wk/wv feed rope + the
     attention core and wo sits behind it — so ``int_chain`` folds each
@@ -376,11 +420,12 @@ def apply_attention(
         kh = apply_rope(kh, positions, a.rope_theta)
 
     if cache is None:
-        kpos = jnp.where(jnp.ones((B, T), bool), positions, -1)
-        out = _sdpa(
-            qh, kh, vh, positions, kpos,
-            causal=a.causal, window=a.window, chunk=a.chunk, q_chunk=q_chunk,
-        )
+        out = _causal_kernel(qh, kh, vh, a, mesh, rules)
+        if out is None:
+            out = _sdpa(
+                qh, kh, vh, positions, positions,
+                causal=a.causal, window=a.window, chunk=a.chunk, q_chunk=q_chunk,
+            )
         new_cache = None
     elif "kp" in cache:  # paged view
         assert view is not None, "paged attention cache needs a block-table view"

@@ -135,6 +135,7 @@ def _apply_block(
     cache: Optional[dict],
     *,
     mesh=None,
+    rules=None,
     ep_axis: Optional[str] = None,
     mla_absorb: bool = False,
     view: Optional[dict] = None,
@@ -153,7 +154,7 @@ def _apply_block(
                 p["attn"], h, s.attn, q, positions, (cache or {}).get("attn"),
                 q_chunk=arch.attn_q_chunk, compute_dtype=cd, mla_absorb=mla_absorb,
                 view=view, decode_kernel=decode_kernel, int_forward=int_forward,
-                int_chain=int_chain,
+                int_chain=int_chain, mesh=mesh, rules=rules,
             )
         if c is not None:
             new_cache["attn"] = c
@@ -189,7 +190,7 @@ def _apply_block(
                 p["attn"], h, s.attn, q, positions, (cache or {}).get("attn"),
                 q_chunk=arch.attn_q_chunk, compute_dtype=cd,
                 view=view, decode_kernel=decode_kernel, int_forward=int_forward,
-                int_chain=int_chain,
+                int_chain=int_chain, mesh=mesh, rules=rules,
             )
         if c is not None:
             new_cache["attn"] = c
@@ -263,6 +264,7 @@ def apply_stack(
     cache=None,
     *,
     mesh=None,
+    rules=None,
     ep_axis: Optional[str] = None,
     mla_absorb: bool = False,
     view: Optional[dict] = None,
@@ -275,8 +277,10 @@ def apply_stack(
     ``view`` (the paged block-table, shared by every layer), ``decode_kernel``
     and ``int_forward``/``int_chain`` (the fused W8A8 serve path and its
     int8-out chaining) pass straight through to the attention / linear
-    layers.  Chained activations never cross a block boundary (every block
-    ends in a residual add — a chain break), so the scan carry stays fp.
+    layers; so do ``mesh`` and its sharding ``rules`` (the training
+    attention kernel runs per chip's heads).  Chained activations never
+    cross a block boundary (every block ends in a residual add — a chain
+    break), so the scan carry stays fp.
     """
 
     def body(carry, layer_in):
@@ -284,7 +288,7 @@ def apply_stack(
         layer_params, layer_cache = layer_in
         xn, new_cache, pen = _apply_block(
             layer_params, xc, arch, s, positions, layer_cache,
-            mesh=mesh, ep_axis=ep_axis, mla_absorb=mla_absorb,
+            mesh=mesh, rules=rules, ep_axis=ep_axis, mla_absorb=mla_absorb,
             view=view, decode_kernel=decode_kernel, int_forward=int_forward,
             int_chain=int_chain,
         )

@@ -153,40 +153,88 @@ def test_a2q_quantize_kernel_budget_invariant():
 
 
 # ---------------------------------------------------------------------------
-# flash attention
+# causal attention (training; splash kernel per KV head) against _sdpa
 # ---------------------------------------------------------------------------
 
-
-@pytest.mark.parametrize("Tq,Tk,causal,window", [
-    (100, 100, True, None),
-    (100, 100, True, 17),
-    (64, 64, False, None),
-    (1, 100, True, None),     # decode
-    (1, 100, True, 32),       # windowed decode
-    (96, 128, True, None),    # Tq < Tk end-aligned
-])
-def test_flash_attention_vs_ref(Tq, Tk, causal, window):
-    B, H, D = 2, 3, 64
-    q = jnp.asarray(RNG.normal(size=(B, H, Tq, D)), jnp.float32)
-    k = jnp.asarray(RNG.normal(size=(B, H, Tk, D)), jnp.float32)
-    v = jnp.asarray(RNG.normal(size=(B, H, Tk, D)), jnp.float32)
-    got = ops.flash_attention(q, k, v, causal=causal, window=window, block_q=32, block_k=32)
-    want = ref.ref_flash_attention(q, k, v, causal=causal, window=window)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+# (H, KV, Dh): smollm-135m's grouping, and one chip's share of yi-6b under
+# tensor parallel 4
+_GROUPINGS = {"smollm": (9, 3, 64), "tp4": (8, 1, 128)}
+_PARTS = ("out", "dq", "dk", "dv")
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_attention_dtypes(dtype):
-    B, H, T, D = 1, 2, 48, 32
-    q = jnp.asarray(RNG.normal(size=(B, H, T, D)), dtype)
-    k = jnp.asarray(RNG.normal(size=(B, H, T, D)), dtype)
-    v = jnp.asarray(RNG.normal(size=(B, H, T, D)), dtype)
-    got = ops.flash_attention(q, k, v, block_q=16, block_k=16)
-    want = ref.ref_flash_attention(q, k, v)
-    atol = 2e-5 if dtype == jnp.float32 else 3e-2
-    np.testing.assert_allclose(
-        np.asarray(got, np.float32), np.asarray(want, np.float32), atol=atol
-    )
+def _qkv_do(H, KV, Dh, T, seed=0):
+    rng = np.random.default_rng(seed)
+    q, k, v = (jnp.asarray(rng.normal(size=(1, T, n, Dh)), jnp.bfloat16) for n in (H, KV, KV))
+    return q, k, v, jnp.asarray(rng.normal(size=(1, T, H, Dh)), jnp.float32)
+
+
+def _out_and_grads(attend, q, k, v, do):
+    """Output and the gradients of ``sum(out * do)`` w.r.t. q, k, v, in f32."""
+    @jax.jit
+    def run(q, k, v, do):
+        out, vjp = jax.vjp(lambda *a: attend(*a).astype(jnp.float32), q, k, v)
+        return (out, *vjp(do))
+
+    return {n: np.asarray(x, np.float32) for n, x in zip(_PARTS, run(q, k, v, do))}
+
+
+def _sdpa_ref(q, k, v):
+    from repro.nn.attention import _sdpa
+
+    T = q.shape[1]
+    pos = jnp.arange(T, dtype=jnp.int32)[None]
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    return _sdpa(*f32, pos, pos, causal=True, window=None, chunk=None, q_chunk=256)
+
+
+_RESULTS: dict = {}
+
+
+def _results(grouping, T):
+    """Kernel and oracle for one case, computed once for all its parts."""
+    if (grouping, T) not in _RESULTS:
+        args = _qkv_do(*_GROUPINGS[grouping], T)
+        _RESULTS[grouping, T] = (_out_and_grads(ops.causal_attention, *args),
+                                 _out_and_grads(_sdpa_ref, *args))
+    return _RESULTS[grouping, T]
+
+
+@pytest.mark.parametrize("part", _PARTS)
+@pytest.mark.parametrize("T", [128, 256])
+@pytest.mark.parametrize("grouping", sorted(_GROUPINGS))
+def test_causal_attention_matches_sdpa(grouping, T, part):
+    """bf16 q/k/v in; the oracle runs on the same values in float32.  The
+    kernel rounds its probabilities and output to bf16 (2^-9 each), so the
+    gap stays within 2^-6 of the largest value."""
+    got, want = (r[part] for r in _results(grouping, T))
+    np.testing.assert_allclose(got, want, rtol=0, atol=2**-6 * np.abs(want).max())
+
+
+@pytest.mark.parametrize("grouping", sorted(_GROUPINGS))
+def test_causal_attention_skips_masked_blocks(grouping):
+    """Blocks above the diagonal are never read.  NaN keys and values at
+    positions from the second block on leave the first block's outputs and
+    query gradients as they were (a dense masked product would give
+    0 x NaN = NaN there); NaN queries and output gradients in the first
+    block leave the key and value gradients of later positions as they
+    were."""
+    from repro.kernels.causal_attention import block_size
+
+    H, KV, Dh = _GROUPINGS[grouping]
+    T = 256
+    b = block_size(T)
+    assert T // b == 2
+    q, k, v, do = _qkv_do(H, KV, Dh, T)
+    clean = _results(grouping, T)[0]
+    late = jnp.arange(T)[None, :, None, None] >= b
+    kv_poisoned = _out_and_grads(ops.causal_attention, q, jnp.where(late, jnp.nan, k),
+                                 jnp.where(late, jnp.nan, v), do)
+    for part in ("out", "dq"):
+        np.testing.assert_array_equal(kv_poisoned[part][:, :b], clean[part][:, :b])
+    q_poisoned = _out_and_grads(ops.causal_attention, jnp.where(late, q, jnp.nan), k, v,
+                                jnp.where(late, do, jnp.nan))
+    for part in ("dk", "dv"):
+        np.testing.assert_array_equal(q_poisoned[part][:, b:], clean[part][:, b:])
 
 
 # ---------------------------------------------------------------------------

@@ -40,6 +40,50 @@ def test_gqa_decode_matches_parallel(qcfg, window, chunk):
     np.testing.assert_allclose(np.asarray(dec), np.asarray(full), atol=1e-4)
 
 
+_DISPATCH = {  # case -> (attention config, T, cached, platform says TPU, kernel runs)
+    "cpu": (AttnConfig(heads=4, kv_heads=2, head_dim=16), 128, False, False, False),
+    "kernel": (AttnConfig(heads=4, kv_heads=2, head_dim=16), 128, False, True, True),
+    "window": (AttnConfig(heads=4, kv_heads=2, head_dim=16, window=32), 128, False, True, False),
+    "chunk": (AttnConfig(heads=4, kv_heads=2, head_dim=16, chunk=32), 128, False, True, False),
+    "mla": (AttnConfig(kind="mla", heads=4, head_dim=16, q_lora_rank=24, kv_lora_rank=16,
+                       qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16), 128, False, True, False),
+    "cached": (AttnConfig(heads=4, kv_heads=2, head_dim=16), 128, True, True, False),
+    "length": (AttnConfig(heads=4, kv_heads=2, head_dim=16), 96, False, True, False),
+    "bidirectional": (AttnConfig(heads=4, kv_heads=2, head_dim=16, causal=False), 128, False,
+                      True, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_DISPATCH))
+def test_training_attention_dispatch(monkeypatch, case):
+    """Only a causal, uncached GQA call whose length is a multiple of the
+    kernel's block takes the causal kernel, and only on a TPU; every other
+    call keeps ``_sdpa`` (here the kernel stands in as ``_sdpa`` itself)."""
+    from repro.kernels import ops
+
+    a, T, cached, tpu, runs = _DISPATCH[case]
+    calls = []
+
+    def kernel(q, k, v):
+        calls.append(q.shape)
+        pos = jnp.arange(q.shape[1], dtype=jnp.int32)[None]
+        return attn._sdpa(q, k, v, pos, pos, causal=True, window=None, chunk=None, q_chunk=256)
+
+    monkeypatch.setattr(ops, "causal_attention", kernel)
+    monkeypatch.setattr(attn, "_on_tpu", lambda: tpu)
+    # shapes only: the choice is made while tracing
+    p = jax.eval_shape(lambda: unbox(attn.init_attention(KEY, 32, a, QF)))
+    x = jax.ShapeDtypeStruct((1, T, 32), jnp.float32)
+    cache = (jax.eval_shape(lambda: attn.init_attn_cache(1, a, max_seq=T, dtype=jnp.float32))
+             if cached else None)
+    pos = jnp.arange(T, dtype=jnp.int32)[None]
+    out, _ = jax.eval_shape(
+        lambda p, x, c: attn.apply_attention(p, x, a, QF, pos, c, compute_dtype=jnp.float32),
+        p, x, cache)
+    assert calls == ([(1, T, 4, 16)] if runs else [])
+    assert out.shape == (1, T, 32)
+
+
 def test_encoder_attention_is_bidirectional():
     a = AttnConfig(heads=2, kv_heads=2, head_dim=8, causal=False, rope_theta=None)
     p = unbox(attn.init_attention(KEY, 16, a, QF))

@@ -489,8 +489,9 @@ def census_runs(tmp_path_factory):
 def _census(run: dict) -> dict:
     out = {}
     for key, v in run["counters"].items():
-        name, kind = key.rstrip("}").split("{kind=")
-        out.setdefault(kind, {})["count" if name == "train_collectives" else "bytes"] = v["value"]
+        if key.startswith("train_collective"):
+            name, kind = key.rstrip("}").split("{kind=")
+            out.setdefault(kind, {})["count" if name == "train_collectives" else "bytes"] = v["value"]
     return out
 
 
@@ -516,6 +517,14 @@ def test_census_tp4_step_all_reduces_per_layer(census_runs):
 def test_census_one_device_step_is_empty(census_runs):
     c = _census(census_runs["one"])
     assert c and all(v == {"count": 0, "bytes": 0} for v in c.values())
+
+
+@pytest.mark.parametrize("run", ["L2", "one"])
+def test_census_no_attention_kernel_off_the_tpu(census_runs, run):
+    """On the CPU the attention keeps ``_sdpa``: every pass reads 0."""
+    counters = census_runs[run]["counters"]
+    assert {p: counters[f"train_attention_kernels{{pass={p}}}"]["value"]
+            for p in ("fwd", "dq", "dkv")} == {"fwd": 0, "dq": 0, "dkv": 0}
 
 
 def test_census_counts_each_compiled_step_once(census_runs):
@@ -591,3 +600,49 @@ def test_census_counts_loop_trips_and_async_pairs_once():
     assert c["reduce-scatter"] == c["all-to-all"] == {"count": 0, "bytes": 0}
     known = _SYNTHETIC_HLO.replace("body=%body", 'body=%body, backend_config={"known_trip_count":{"n":"5"}}')
     assert collective_census(known)["all-reduce"]["count"] == 5 + 1
+
+
+# The training attention kernel's custom calls as the TPU compiler names
+# them (operands cut short): the forward, dq and dkv passes of a scanned
+# layer's backward inside the loop, a forward outside it, and a fusion and a
+# custom call that are not the kernel.
+_KERNEL_HLO = _SYNTHETIC_HLO.replace(
+    "  ROOT %t = (s32[], bf16[8,128]) tuple(%i, %ar)",
+    """  %splash_mqa_fwd_residuals.1 = (f32[2,512,128]{2,1,0:T(8,128)}, bf16[2,3,2048,64]{3,2,1,0}) custom-call(%x), custom_call_target="tpu_custom_call", backend_config={}
+  %splash_mqa_dq_no_residuals.1 = (f32[2,512,64]{2,1,0:T(8,128)}, bf16[2,3,2048,64]{3,2,1,0}) custom-call(%x), custom_call_target="tpu_custom_call"
+  %splash_mqa_dkv_no_residuals = (bf16[2,2048,64]{2,1,0}, bf16[2,2048,64]{2,1,0}) custom-call(%x), custom_call_target="tpu_custom_call"
+  ROOT %t = (s32[], bf16[8,128]) tuple(%i, %ar)""").replace(
+    "  ROOT %r = bf16[8,128] get-tuple-element(%w), index=1",
+    """  %splash_mqa_fwd_no_residuals.2 = bf16[2,3,2048,64]{3,2,1,0} custom-call(%a), custom_call_target="tpu_custom_call"
+  %splash_mqa_dq.3 = bf16[8,128] fusion(%a), kind=kLoop, calls=%add
+  %custom-call = bf16[8,128] custom-call(%a), custom_call_target="ConcatBitcast"
+  ROOT %r = bf16[8,128] get-tuple-element(%w), index=1""")
+
+
+def test_attention_kernel_census_counts_passes_per_trip():
+    """Each pass of the kernel counts once per loop trip, by its kernel's
+    name on a ``tpu_custom_call``; the collectives read as before."""
+    from repro.dist.census import attention_kernel_census, collective_census
+
+    assert attention_kernel_census(_KERNEL_HLO) == {"fwd": 3 + 1, "dq": 3, "dkv": 3}
+    assert attention_kernel_census(_SYNTHETIC_HLO) == {"fwd": 0, "dq": 0, "dkv": 0}
+    assert collective_census(_KERNEL_HLO) == collective_census(_SYNTHETIC_HLO)
+
+
+def test_record_census_counts_attention_kernels():
+    """``record_census`` puts the kernel's passes of the compiled step on
+    ``train_attention_kernels{pass}`` beside the collectives."""
+    from types import SimpleNamespace
+
+    from repro.launch.train import record_census
+    from repro.obs import Obs
+
+    compiled = SimpleNamespace(as_text=lambda: _KERNEL_HLO)
+    trainer = SimpleNamespace(
+        obs=Obs(), batch_fn=lambda step: step, shard_batch=lambda b: b,
+        step_fn=SimpleNamespace(lower=lambda state, batch: SimpleNamespace(compile=lambda: compiled)))
+    record_census(trainer, None, 0)
+    counters = trainer.obs.metrics.snapshot()
+    assert {p: counters[f"train_attention_kernels{{pass={p}}}"]["value"]
+            for p in ("fwd", "dq", "dkv")} == {"fwd": 4, "dq": 3, "dkv": 3}
+    assert counters["train_collectives{kind=all-reduce}"]["value"] == 3 + 1

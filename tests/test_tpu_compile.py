@@ -6,8 +6,10 @@ the TPU compiler for a *described* v5e chip — nothing runs, no chip is
 needed — at the widths the serve path uses: yi-6b's d_model 4096 / d_ff
 11008 matmuls with the A2Q int16 spill, its GQA decode (32 query heads over
 4 KV heads, head_dim 128) over fp / int8 / packed-int4 pools, and the MLA
-latent decode at deepseek-v3's rank.  Each compile must contain the kernel
-(``tpu_custom_call``), i.e. no interpret-mode fallback slipped in.
+latent decode at deepseek-v3's rank, and the training attention with its
+backward at the training cells' shapes, on one chip and per chip's heads
+under the four-chip tensor-parallel mesh.  Each compile must contain the
+kernel (``tpu_custom_call``), i.e. no interpret-mode fallback slipped in.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and test workers import every file.
@@ -107,3 +109,56 @@ def test_paged_mla_attention_compiles(one_chip, kv):
             ql, qp, c, k, bt, ln, ckvs=cs, kpes=ks, scale=scale, aq_scale=a,
             act_bits=8, interpret=False)
     _compile(fn, one_chip, *shapes)
+
+
+def _attention_grad(attend):
+    return jax.value_and_grad(lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32)),
+                              argnums=(0, 1, 2))
+
+
+def test_causal_attention_compiles(one_chip):
+    """Training attention with its backward at smollm135m-train-a2q's shapes
+    (20 rows of 2048 tokens, 9 query heads over 3 KV heads of 64): one
+    forward and one of each backward kernel."""
+    from repro.dist.census import attention_kernel_census
+
+    B, T, H, KV, Dh = 20, 2048, 9, 3, 64
+    fn = _attention_grad(lambda q, k, v: ops.causal_attention(q, k, v, interpret=False))
+    text = _compile(fn, one_chip, ((B, T, H, Dh), jnp.bfloat16), ((B, T, KV, Dh), jnp.bfloat16),
+                    ((B, T, KV, Dh), jnp.bfloat16))
+    assert attention_kernel_census(text) == {"fwd": 1, "dq": 1, "dkv": 1}
+
+
+def test_causal_attention_compiles_per_chip_heads(topo, monkeypatch):
+    """Under yi6b-train-tp4's mesh (data 1 x model 4 on a v5e 2x2; 4 rows of
+    4096 tokens, 32 query heads over 4 KV heads of 128) the kernel runs in a
+    ``shard_map`` over each chip's 8 query heads and its KV head: no chip
+    gathers, exchanges or passes on another's heads."""
+    import functools
+
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.configs import get_arch
+    from repro.dist.census import attention_kernel_census, collective_census
+    from repro.dist.sharding import ShardingRules, make_mesh
+    from repro.nn import attention as attn
+
+    arch = get_arch("yi-6b")
+    a = arch.stacks[0].attn
+    mesh = make_mesh((1, 4), ("data", "model"), devices=topo.devices)
+    rules = ShardingRules.default(mesh, arch)
+    monkeypatch.setattr(attn, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ops, "causal_attention",
+                        functools.partial(ops.causal_attention, interpret=False))
+    fn = _attention_grad(lambda q, k, v: attn._causal_kernel(q, k, v, a, mesh, rules))
+    heads = NamedSharding(mesh, P(None, None, "model", None))
+    B, T = 4, 4096
+    args = [jax.ShapeDtypeStruct((B, T, n, a.head_dim), jnp.bfloat16, sharding=heads)
+            for n in (a.heads, a.kv_heads, a.kv_heads)]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert attention_kernel_census(text) == {"fwd": 1, "dq": 1, "dkv": 1}
+    census = collective_census(text)
+    assert all(census[k]["count"] == 0
+               for k in ("all-gather", "all-to-all", "collective-permute")), census
